@@ -79,6 +79,9 @@ def main(argv=None) -> None:
     if argv:
         raise SystemExit(f"unknown arguments: {argv}")
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         accuracy,
         baselines,

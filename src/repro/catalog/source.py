@@ -17,7 +17,7 @@ from __future__ import annotations
 import abc
 import hashlib
 import os
-from typing import Dict, List
+from typing import Dict, List, Optional
 
 from repro.columnar import format as fmt
 from repro.columnar import reader as rd
@@ -68,10 +68,19 @@ class PQLiteMetadataSource(MetadataSource):
 
 
 class InMemoryMetadataSource(MetadataSource):
-    """Footers held in memory — tests, synthetic fleets, RPC ingestion stubs."""
+    """Footers held in memory — tests, synthetic fleets, RPC ingestion stubs.
 
-    def __init__(self, footers: Dict[str, fmt.FileFooter]):
+    ``root``, when given, is a directory for the catalog's estimate-cache
+    spill (`StatsCatalog.save_cache`), which a fleet replica needs; the
+    footers themselves never touch it.
+    """
+
+    def __init__(
+        self, footers: Dict[str, fmt.FileFooter], root: Optional[str] = None
+    ):
         self._footers = dict(footers)
+        if root is not None:
+            self.root = root
 
     def list_files(self) -> List[str]:
         return sorted(self._footers)

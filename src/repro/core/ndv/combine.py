@@ -132,11 +132,13 @@ def combine_estimates(
     # The estimate is only a lower bound when the *winning* signal said so:
     #  - dict wins while flagged as plain-encoding fallback, or
     #  - minmax wins while coupon-saturated (m == n) on sorted data.
+    # (A bool select written as logic: Mosaic cannot lower a select of bool
+    # vectors, and the fused kernel runs this code.)
     dict_wins = ndv_dict >= ndv_minmax
-    is_lower_bound = jnp.where(
-        dict_wins,
-        ~dict_ok,
-        minmax_saturated & (jnp.asarray(layout) != int(Layout.SORTED)),
+    is_lower_bound = (dict_wins & ~dict_ok) | (
+        ~dict_wins
+        & minmax_saturated
+        & (jnp.asarray(layout) != int(Layout.SORTED))
     )
     if suspect_clustered is not None:
         # Clustered signature (overlapping ranges + saturated extrema

@@ -34,12 +34,17 @@ def ceil_log2(ndv: jnp.ndarray) -> jnp.ndarray:
     """ceil(log2(ndv)) with ceil_log2(1) == 1 (1 bit minimum index width).
 
     Parquet's RLE/bit-packed hybrid needs at least 1 bit per index even for a
-    single-entry dictionary, so we clamp below at 1 bit. Uses float log2 with
-    a tiny epsilon nudge so exact powers of two are stable.
+    single-entry dictionary, so we clamp below at 1 bit. Read exactly off
+    the float32 bits (exponent, plus one unless the mantissa is zero), not
+    off a float log2: log2 rounds differently per backend (the TPU kernel's
+    can land just above k at 2^k), and one bit of index width moves Eq 1's
+    root by rows/8/len.
     """
-    ndv = jnp.maximum(ndv, 1.0)
-    bits = jnp.ceil(jnp.log2(ndv) - 1e-9)
-    return jnp.maximum(bits, 1.0)
+    x = jnp.maximum(jnp.asarray(ndv, jnp.float32), 1.0)
+    raw = jax.lax.bitcast_convert_type(x, jnp.int32)
+    exponent = (raw >> 23) - 127  # x >= 1: sign bit clear
+    inexact = ((raw & 0x7FFFFF) != 0).astype(jnp.int32)
+    return jnp.maximum(exponent + inexact, 1).astype(jnp.float32)
 
 
 def smooth_log2(ndv: jnp.ndarray) -> jnp.ndarray:
@@ -171,23 +176,25 @@ def invert_dict_size(
     # Relative tolerance scale: sizes span bytes..TB, so scale by S.
     scale = jnp.maximum(size, 1.0)
 
-    def body(_, carry):
-        ndv, it, done = carry
+    def body(i, carry):
+        ndv, it = carry
+        # A lane stops counting at convergence, so its count lags the step
+        # index exactly when it is done (no bool loop carry: Mosaic cannot
+        # carry one).
+        done = it < i
         f = residual(ndv, size, mean_len, non_null)
         fp = residual_derivative(ndv, mean_len, non_null)
         step = f / fp
         new_ndv = jnp.clip(ndv - step, 1.0, jnp.maximum(non_null, 1.0))
-        now_done = jnp.abs(f) <= tol * scale
-        ndv = jnp.where(done | now_done, ndv, new_ndv)
-        it = it + jnp.where(done | now_done, 0, 1).astype(jnp.int32)
-        return ndv, it, done | now_done
+        stop = done | (jnp.abs(f) <= tol * scale)
+        ndv = jnp.where(stop, ndv, new_ndv)
+        it = it + jnp.where(stop, 0, 1).astype(jnp.int32)
+        return ndv, it
 
-    ndv, iters_used, converged = jax.lax.fori_loop(
-        0,
-        iters,
-        body,
-        (ndv0, jnp.zeros_like(size, jnp.int32), jnp.zeros_like(size, bool)),
+    ndv, iters_used = jax.lax.fori_loop(
+        0, iters, body, (ndv0, jnp.zeros_like(size, jnp.int32))
     )
+    converged = iters_used < iters
     # The ceiling makes f piecewise-linear in ndv with jumps at powers of 2;
     # after Newton converges on the smooth surrogate's root, snap within the
     # final bit-width plateau by re-solving the linear piece exactly:

@@ -45,6 +45,7 @@ def detect_distribution(
     valid: jnp.ndarray,
     *,
     backend: str = "auto",
+    keepdims: bool = False,
 ) -> DistributionMetrics:
     """Compute Eq 10-12 metrics and classify (§6.2), batched.
 
@@ -54,6 +55,8 @@ def detect_distribution(
       backend: "auto"/"ref" compute the reductions here in jnp; "pallas"
         (or "auto" on TPU) takes them from the `minmax_scan` kernel. The
         ratio/classification tail is shared.
+      keepdims: return (B, 1) columns instead of (B,) vectors — the form
+        the estimation pipeline computes in (see `estimate_batch_core`).
 
     Returns:
       DistributionMetrics with int32 layout codes from `Layout`.
@@ -66,26 +69,26 @@ def detect_distribution(
 
     if ops.use_pallas(backend):
         mm = ops.minmax_scan(mins, maxs, valid, backend="pallas")
-        n = mm.n_valid
-        gmin, gmax = mm.gmin, mm.gmax
-        overlap_sum = mm.overlap_sum
-        sign_changes = mm.sign_changes
+        n = mm.n_valid[:, None]
+        gmin, gmax = mm.gmin[:, None], mm.gmax[:, None]
+        overlap_sum = mm.overlap_sum[:, None]
+        sign_changes = mm.sign_changes[:, None]
         # Row groups are packed to the left, so "any valid consecutive
         # pair" is exactly n >= 2.
         any_pairs = n >= 2.0
     else:
-        n = jnp.sum(valid, axis=-1).astype(jnp.float32)  # (B,)
+        n = jnp.sum(valid, axis=-1, keepdims=True).astype(jnp.float32)
 
         big = jnp.float32(3.4e38)
-        gmin = jnp.min(jnp.where(valid, mins, big), axis=-1)
-        gmax = jnp.max(jnp.where(valid, maxs, -big), axis=-1)
+        gmin = jnp.min(jnp.where(valid, mins, big), axis=-1, keepdims=True)
+        gmax = jnp.max(jnp.where(valid, maxs, -big), axis=-1, keepdims=True)
 
         # Consecutive-pair overlap (Eq 10), masked to valid pairs.
         pair_valid = valid[:, :-1] & valid[:, 1:]
         lo = jnp.maximum(mins[:, :-1], mins[:, 1:])
         hi = jnp.minimum(maxs[:, :-1], maxs[:, 1:])
         overlap = jnp.where(pair_valid, jnp.maximum(hi - lo, 0.0), 0.0)
-        overlap_sum = jnp.sum(overlap, axis=-1)
+        overlap_sum = jnp.sum(overlap, axis=-1, keepdims=True)
 
         # Midpoint monotonicity (Eq 12).
         mid = (mins + maxs) * 0.5
@@ -97,8 +100,8 @@ def detect_distribution(
         changes = jnp.where(
             step_valid & (sgn[:, :-1] * sgn[:, 1:] < 0), 1.0, 0.0
         )
-        sign_changes = jnp.sum(changes, axis=-1)
-        any_pairs = jnp.sum(pair_valid, axis=-1) > 0
+        sign_changes = jnp.sum(changes, axis=-1, keepdims=True)
+        any_pairs = jnp.sum(pair_valid, axis=-1, keepdims=True) > 0
 
     total_span = jnp.maximum(gmax - gmin, 0.0)
 
@@ -119,12 +122,13 @@ def detect_distribution(
     )
 
     layout = classify(overlap_ratio, monotonicity, n)
-    return DistributionMetrics(
+    out = DistributionMetrics(
         overlap_ratio=overlap_ratio,
         monotonicity=monotonicity,
         total_span=total_span,
         layout=layout,
     )
+    return out if keepdims else DistributionMetrics(*(x[:, 0] for x in out))
 
 
 def classify(

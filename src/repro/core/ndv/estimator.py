@@ -78,33 +78,48 @@ def dict_estimate_column(
     ``residual`` is the worst |Eq 2 residual| / S across the column's valid
     chunks at the converged roots — the solver's own error signal, surfaced
     for provenance (a large value means Eq 1 never fit that chunk's size).
+
+    ``batch`` is in column form and so are the results: (B, 1) per column.
     """
     inv = dict_inversion.invert_dict_size(
         batch.chunk_S,
         batch.chunk_rows,
         batch.chunk_nulls,
-        batch.mean_len[:, None],
+        batch.mean_len,
         backend=backend,
     )
     usable = batch.valid & batch.chunk_dict_encoded & ~inv.likely_fallback
     neg = jnp.float32(-1.0)
-    ndv_usable = jnp.max(jnp.where(usable, inv.ndv, neg), axis=-1)
+    ndv_usable = jnp.max(
+        jnp.where(usable, inv.ndv, neg), axis=-1, keepdims=True
+    )
     # Fallback path: no usable dictionary chunk -> max over ALL valid chunks
     # (plain chunks invert to ~rows; Eq 5 semantics: a lower bound).
-    ndv_any = jnp.max(jnp.where(batch.valid, inv.ndv, neg), axis=-1)
+    ndv_any = jnp.max(
+        jnp.where(batch.valid, inv.ndv, neg), axis=-1, keepdims=True
+    )
     no_usable = ndv_usable < 0.0
     ndv_col = jnp.where(no_usable, ndv_any, ndv_usable)
     ndv_col = jnp.maximum(ndv_col, 1.0)
     fallback_col = no_usable
-    iters = jnp.max(jnp.where(batch.valid, inv.iterations, 0), axis=-1)
+    iters = jnp.max(
+        jnp.where(batch.valid, inv.iterations, 0), axis=-1, keepdims=True
+    )
     chunk_non_null = jnp.maximum(batch.chunk_rows - batch.chunk_nulls, 0.0)
     resid = jnp.abs(
         dict_inversion.residual(
-            inv.ndv, batch.chunk_S, batch.mean_len[:, None], chunk_non_null
+            inv.ndv, batch.chunk_S, batch.mean_len, chunk_non_null
         )
     ) / jnp.maximum(batch.chunk_S, 1.0)
-    resid = jnp.max(jnp.where(batch.valid, resid, 0.0), axis=-1)
+    resid = jnp.max(jnp.where(batch.valid, resid, 0.0), axis=-1, keepdims=True)
     return ndv_col, fallback_col, iters, resid.astype(jnp.float32)
+
+
+# The ColumnBatch fields that hold one value per column.
+_PER_COLUMN = (
+    "N", "nulls", "n_groups", "m_min", "m_max", "mean_len", "len_sample",
+    "fixed_width", "int_like", "single_byte",
+)
 
 
 def estimate_batch_core(
@@ -114,17 +129,40 @@ def estimate_batch_core(
     mode: str = "paper",
     backend: str = "auto",
 ) -> BatchEstimates:
-    """The unjitted §4-§7 pipeline body: ColumnBatch tiles in, estimates out.
+    """The unjitted §4-§7 pipeline body: ColumnBatch in, (B,) estimates out.
 
     Shared verbatim by the unfused `estimate_batch` path and (with
-    ``backend="ref"``) by the fused megakernel's body and its oracle
-    (`repro.kernels.fused_estimate` / `repro.kernels.ref.ref_fused_estimate`)
-    — one definition of the numerics is what makes the fuse knob provably
-    numerics-neutral.
+    ``backend="ref"``) by the fused megakernel's oracle
+    (`repro.kernels.ref.ref_fused_estimate`); the megakernel's body runs
+    `estimate_tile`, the same pipeline in column form — one definition of
+    the numerics is what makes the fuse knob provably numerics-neutral.
+    """
+    cols = dataclasses.replace(
+        batch, **{f: getattr(batch, f)[:, None] for f in _PER_COLUMN}
+    )
+    sb = None if schema_bound is None else schema_bound[:, None]
+    est = estimate_tile(cols, sb, mode=mode, backend=backend)
+    return BatchEstimates(*(x[:, 0] for x in est))
+
+
+def estimate_tile(
+    batch: ColumnBatch,
+    schema_bound: Optional[jnp.ndarray] = None,
+    *,
+    mode: str = "paper",
+    backend: str = "auto",
+) -> BatchEstimates:
+    """The §4-§7 pipeline in column form.
+
+    Every per-column value, in ``batch``, ``schema_bound`` and the result,
+    is a (B, 1) column rather than a (B,) vector, and every reduction over
+    the row-group axis keeps its dims. That keeps the whole pipeline 2-D,
+    the form a Pallas TPU kernel body can lower (Mosaic has no 1-D bool
+    vectors), so the megakernel runs this function on its tiles as is.
     """
     # --- §6: distribution detection --------------------------------------
     metrics = distribution.detect_distribution(
-        batch.mins, batch.maxs, batch.valid, backend=backend
+        batch.mins, batch.maxs, batch.valid, backend=backend, keepdims=True
     )
 
     # --- §4: dictionary size inversion (per chunk -> column aggregate) ----
@@ -151,8 +189,12 @@ def estimate_batch_core(
 
     # --- §7: combine -------------------------------------------------------
     big = jnp.float32(3.4e38)
-    gmin = jnp.min(jnp.where(batch.valid, batch.mins, big), axis=-1)
-    gmax = jnp.max(jnp.where(batch.valid, batch.maxs, -big), axis=-1)
+    gmin = jnp.min(
+        jnp.where(batch.valid, batch.mins, big), axis=-1, keepdims=True
+    )
+    gmax = jnp.max(
+        jnp.where(batch.valid, batch.maxs, -big), axis=-1, keepdims=True
+    )
     non_null = batch.N - batch.nulls
     # Clustered signature: range overlap says "well-spread" while the
     # extrema diversity saturates — runs are hiding the domain tail.
@@ -241,8 +283,8 @@ def estimate_batch(
         single `pallas_call` (`repro.kernels.fused_estimate`) where the
         kernel path is production, the pure-XLA twin elsewhere — instead of
         3-4 kernel dispatches plus XLA glue. Numerics-neutral by the engine
-        parity contract (the fused body IS `estimate_batch_core` with the
-        reference backend), so the knob never enters
+        parity contract (the fused body runs `estimate_tile`, the body of
+        `estimate_batch_core`, with the reference backend), so the knob never enters
         `cache_key`/`cache_token`. "off" pins the unfused per-stage path.
     """
     from repro.kernels import ops  # local: kernels.ref imports this module

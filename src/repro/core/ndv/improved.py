@@ -46,12 +46,17 @@ def improved_dict_estimate(
     *,
     backend: str = "auto",
 ) -> ImprovedDictResult:
-    """Layout-aware aggregation of per-chunk dictionary inversions."""
+    """Layout-aware aggregation of per-chunk dictionary inversions.
+
+    ``batch`` carries its per-column fields as (B, 1) columns and
+    ``overlap_ratio`` is (B, 1); so are the results (the pipeline's
+    column form, see `estimate_batch_core`).
+    """
     inv = dict_inversion.invert_dict_size(
         batch.chunk_S,
         batch.chunk_rows,
         batch.chunk_nulls,
-        batch.mean_len[:, None],
+        batch.mean_len,
         backend=backend,
     )
     usable = batch.valid & batch.chunk_dict_encoded & ~inv.likely_fallback
@@ -67,17 +72,18 @@ def improved_dict_estimate(
     corrected = jnp.where(usable, corr.ndv, -1.0)
     # Aggregate robustly: mean over usable chunks (each chunk is an i.i.d.
     # estimate of the same global NDV under the well-spread assumption).
-    n_usable = jnp.maximum(jnp.sum(usable, axis=-1), 1)
+    n_usable = jnp.maximum(jnp.sum(usable, axis=-1, keepdims=True), 1)
     corrected_mean = (
-        jnp.sum(jnp.where(usable, corr.ndv, 0.0), axis=-1) / n_usable
+        jnp.sum(jnp.where(usable, corr.ndv, 0.0), axis=-1, keepdims=True)
+        / n_usable
     )
-    corrected_max = jnp.max(corrected, axis=-1)
+    corrected_max = jnp.max(corrected, axis=-1, keepdims=True)
     # Saturated correction (local_ndv ~ rows) means the chunk cannot bound
     # NDV from metadata; fall back to the uncorrected max there.
     ndv_corrected = jnp.where(corrected_max > 0, corrected_mean, 1.0)
 
     # --- (2) disjoint-sum aggregation --------------------------------------
-    local_sum = jnp.sum(jnp.where(usable, inv.ndv, 0.0), axis=-1)
+    local_sum = jnp.sum(jnp.where(usable, inv.ndv, 0.0), axis=-1, keepdims=True)
     # Exact boundary dedup: consecutive chunks sharing a value have
     # max_i == min_{i+1} in the footer stats.
     shared = (
@@ -85,7 +91,9 @@ def improved_dict_estimate(
         & batch.valid[:, :-1]
         & batch.valid[:, 1:]
     )
-    local_sum = jnp.maximum(local_sum - jnp.sum(shared, axis=-1), 1.0)
+    local_sum = jnp.maximum(
+        local_sum - jnp.sum(shared, axis=-1, keepdims=True), 1.0
+    )
 
     # --- routing ------------------------------------------------------------
     # overlap_ratio ~ 0  -> ranges disjoint -> sum is (near) exact.
@@ -97,13 +105,17 @@ def improved_dict_estimate(
     ndv = jnp.exp(log_est)
 
     # Never below the plain per-chunk max (that is a hard lower bound).
-    hard_floor = jnp.maximum(jnp.max(jnp.where(usable, inv.ndv, 1.0), axis=-1), 1.0)
+    hard_floor = jnp.maximum(
+        jnp.max(jnp.where(usable, inv.ndv, 1.0), axis=-1, keepdims=True), 1.0
+    )
     ndv = jnp.maximum(ndv, hard_floor)
 
     # Column-level fallback: no usable dictionary chunk at all.
-    no_usable = jnp.sum(usable, axis=-1) == 0
+    no_usable = jnp.sum(usable, axis=-1, keepdims=True) == 0
     neg = jnp.float32(-1.0)
-    ndv_any = jnp.max(jnp.where(batch.valid, inv.ndv, neg), axis=-1)
+    ndv_any = jnp.max(
+        jnp.where(batch.valid, inv.ndv, neg), axis=-1, keepdims=True
+    )
     ndv = jnp.where(no_usable, jnp.maximum(ndv_any, 1.0), ndv)
     return ImprovedDictResult(
         ndv=ndv,

@@ -34,18 +34,41 @@ import jax.numpy as jnp
 NEWTON_ITERS = 40
 NEWTON_TOL = 1e-6
 
+# Below this |x|, `expm1` sums its Taylor series instead of exp(x) - 1.
+_EXPM1_SERIES_BOUND = 0.5
+_EXPM1_TERMS = 10
+
+
+def expm1(x: jnp.ndarray) -> jnp.ndarray:
+    """exp(x) - 1 without cancellation near 0, from ops Pallas TPU lowers.
+
+    The coupon-collector terms need 1 - e^{-r} at tiny r (NDV >> draws),
+    where 1 - exp(-r) cancels to zero; `jnp.expm1` has no Pallas TPU
+    lowering, and the fused kernel runs this math. For |x| < 0.5 the
+    Taylor series in Horner form, x(1 + x/2(1 + x/3(...))), is accurate
+    to a few ulp (truncation after 10 terms is below 1e-9 relative);
+    elsewhere exp(x) - 1 loses at most a bit to cancellation.
+    """
+    x = jnp.asarray(x, jnp.float32)
+    small = jnp.abs(x) < _EXPM1_SERIES_BOUND
+    xs = jnp.where(small, x, 0.0)
+    series = jnp.ones_like(xs)
+    for k in range(_EXPM1_TERMS, 1, -1):
+        series = 1.0 + xs / float(k) * series
+    return jnp.where(small, xs * series, jnp.exp(x) - 1.0)
+
 
 def coupon_expected(ndv: jnp.ndarray, n: jnp.ndarray) -> jnp.ndarray:
     """E[distinct] = NDV*(1-exp(-n/NDV)) (Eq 6), safe at ndv -> 0."""
     ndv = jnp.maximum(ndv, 1e-9)
-    return ndv * -jnp.expm1(-n / ndv)
+    return ndv * -expm1(-n / ndv)
 
 
 def coupon_derivative(ndv: jnp.ndarray, n: jnp.ndarray) -> jnp.ndarray:
     """g'(NDV) (Eq 9)."""
     ndv = jnp.maximum(ndv, 1e-9)
     r = n / ndv
-    return -jnp.expm1(-r) - jnp.exp(-r) * r
+    return -expm1(-r) - jnp.exp(-r) * r
 
 
 class CouponInversionResult(NamedTuple):
@@ -105,21 +128,25 @@ def invert_coupon(
     ndv0 = jnp.clip(n * n / (2.0 * jnp.maximum(n - m_eff, 1e-3)), 1.0, 1e12)
     t0 = jnp.log(ndv0)
 
-    def body(_, carry):
-        t, it, done = carry
+    def body(i, carry):
+        t, it = carry
+        # A lane stops counting at convergence, so its count lags the step
+        # index exactly when it is done (no bool loop carry: Mosaic cannot
+        # carry one).
+        done = it < i
         ndv = jnp.exp(t)
         g = coupon_expected(ndv, n) - m_eff
         gp = coupon_derivative(ndv, n)
         # d/dt g(exp(t)) = g'(ndv) * ndv
         step = g / jnp.maximum(gp * ndv, 1e-12)
         new_t = jnp.clip(t - step, 0.0, 28.0)  # NDV in [1, ~1.4e12]
-        now_done = jnp.abs(g) <= tol * jnp.maximum(m_eff, 1.0)
-        t = jnp.where(done | now_done, t, new_t)
-        it = it + jnp.where(done | now_done, 0, 1).astype(jnp.int32)
-        return t, it, done | now_done
+        stop = done | (jnp.abs(g) <= tol * jnp.maximum(m_eff, 1.0))
+        t = jnp.where(stop, t, new_t)
+        it = it + jnp.where(stop, 0, 1).astype(jnp.int32)
+        return t, it
 
-    t, iters_used, _ = jax.lax.fori_loop(
-        0, iters, body, (t0, jnp.zeros_like(m, jnp.int32), jnp.zeros_like(m, bool))
+    t, iters_used = jax.lax.fori_loop(
+        0, iters, body, (t0, jnp.zeros_like(m, jnp.int32))
     )
     ndv = jnp.exp(t)
     # Saturated observations (m == n) carry no upper-bound information: the
@@ -158,7 +185,8 @@ def estimate_minmax_diversity(
     hi = invert_coupon(m_max, n_groups, backend=backend)
     take_hi = hi.ndv >= lo.ndv
     ndv = jnp.where(take_hi, hi.ndv, lo.ndv)
-    saturated = jnp.where(take_hi, hi.saturated, lo.saturated)
+    # Bool select as logic: Mosaic cannot lower a select of bool vectors.
+    saturated = (take_hi & hi.saturated) | (~take_hi & lo.saturated)
     return MinMaxDiversityResult(
         ndv=ndv,
         ndv_from_min=lo.ndv,

@@ -14,7 +14,6 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.catalog.packer import BatchPacker
@@ -51,20 +50,25 @@ _DISPATCHES = registry().counter(
 
 
 def detect_device_memory() -> Optional[int]:
-    """Bytes of memory on the first visible device, or None.
+    """Bytes of memory on the first visible device; None on the host CPU.
 
-    Uses the allocator's `memory_stats()` report (present on TPU/GPU
-    backends; host CPU returns nothing). Any failure means "unknown" — the
-    auto budget then falls back to `DEFAULT_MAX_BATCH`.
+    Accelerators (TPU/GPU) report it through the allocator's
+    `memory_stats()`. The host CPU reports nothing, and the auto budget
+    then falls back to `DEFAULT_MAX_BATCH`. On an accelerator a failed or
+    empty report raises: shrinking the budget to the CPU default would
+    hide a broken device behind a slower, still-working engine.
     """
-    try:
-        stats = jax.local_devices()[0].memory_stats()
-    except Exception:
+    dev = jax.local_devices()[0]
+    if dev.platform == "cpu":
         return None
-    if not stats:
-        return None
+    stats = dev.memory_stats() or {}
     limit = stats.get("bytes_limit") or stats.get("bytes_reservable_limit")
-    return int(limit) if limit else None
+    if not limit:
+        raise RuntimeError(
+            f"{dev.platform} device {dev.device_kind!r} reports no memory "
+            f"limit (memory_stats() = {stats!r})"
+        )
+    return int(limit)
 
 
 def auto_chunk_budget(mem_bytes: Optional[int], shards: int = 1) -> int:
@@ -126,14 +130,14 @@ def _sharded_fn(devices: tuple, mode: str, backend: str, fuse: str = "auto"):
     """
     mesh = Mesh(np.asarray(devices), ("cols",))
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             functools.partial(
                 estimate_batch, mode=mode, backend=backend, fuse=fuse
             ),
             mesh=mesh,
             in_specs=(P("cols"), P("cols")),
             out_specs=P("cols"),
-            check_rep=False,
+            check_vma=False,
         )
     )
 

@@ -39,7 +39,7 @@ Provenance lanes: both the megakernel and the unfused pipeline emit
 per-column diagnostics as extra output lanes of the SAME program —
 route chosen + decision margin, detector margin, Newton iteration
 counts and final dict residual, clamp flags (see fused_estimate.py
-``_OUT_ROUTE..._OUT_CLAMP_FLAGS``). Because they are outputs of the
+``_OUT_LANES``). Because they are outputs of the
 shared numerics rather than a side channel, fused and ref twins agree
 on them bit-for-bit off-TPU, the strategy x device parity matrix pins
 them across serving topologies, and they can never perturb estimates

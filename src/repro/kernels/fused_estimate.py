@@ -5,7 +5,7 @@ The separate kernel path costs 4 `pallas_call` launches per estimate — the
 XLA glue (masked aggregations, Eq 13-15 combine) between them, with every
 intermediate bouncing through HBM. Serving-shaped workloads (one catalog
 lookup per query-optimizer probe) are launch-bound, not FLOP-bound, so this
-kernel runs the entire pipeline per (BLOCK_B, R) column tile in one launch:
+kernel runs the entire pipeline per (block_b, R) column tile in one launch:
 detector metrics, both Newton inversions, and the branchless
 `jnp.where`-select of Eq 13 on the detector verdict, all on VMEM-resident
 tiles.
@@ -13,8 +13,8 @@ tiles.
 Numerics contract (what lets `EngineConfig.fuse` stay out of
 `cache_key`/`cache_token`): the body does not reimplement anything — it
 reconstructs a tile-shaped `ColumnBatch` from its refs and calls
-`estimate_batch_core(..., backend="ref")`, i.e. the REFERENCE pipeline, the
-same function the unfused production path runs. The dispatch layer
+`estimate_tile(..., backend="ref")`, i.e. the REFERENCE pipeline in the
+column form that `estimate_batch_core` itself runs. The dispatch layer
 (`repro.kernels.ops.fused_estimate`) compiles this kernel only where the
 kernel path is the production path (TPU, or an explicit ``backend="pallas"``
 pin); everywhere else it routes to the pure-XLA twin
@@ -29,19 +29,20 @@ the same way: tight allclose plus exact discrete fields.)
 
 I/O layout: seven (B, R) float32 planes (bools as 0/1, reconstructed with
 `> 0.5`), per-column scalars packed into one (B, LANES) float32 array, and
-one (B, LANES) float32 output with results in the leading lanes. Lane
-packing follows `minmax_scan`: every scalar is either an exact small int, a
-0/1 flag, or already float32, so the trip through lanes is exact.
+one (B, LANES) float32 output with results in the leading lanes. Every
+scalar is either an exact small int, a 0/1 flag, or already float32, so the
+trip through lanes is exact. Inside the body each scalar is a (block_b, 1)
+column, never a 1-D vector, and results are packed with a lane-iota select
+(`minmax_scan.pack_lanes`).
 
-The whole batch is ONE block (grid=(1,)), B and R both carried whole — no
-in-kernel re-tiling. Bounding B per dispatch is the ENGINE's job: the
-chunked/composed strategies already stream `max_batch`-wide slices, so each
-fused launch sees an engine-bounded block (size that budget to VMEM when
-compiling for real TPUs).
-
-These kernels target TPU; in this container they are validated with
-``interpret=True`` against `repro.kernels.ref.ref_fused_estimate` (the same
-core called outside any kernel).
+Tiling: the grid walks B in blocks of `block_rows(R)` columns, with R kept
+whole per step so every row-group reduction stays tile-local. The block
+shrinks as R grows so that a step's planes and the pipeline's (block_b, R)
+temporaries fit the TPU's default scoped VMEM up to R = 8192 at the
+8-column floor; wider R raises the scoped limit in proportion, up to
+`MAX_GROUPS` (see `vmem_limit`). B is zero-padded to a whole number of
+blocks and R to at least `MIN_GROUPS`: zeros are the packer's own fully
+masked padding.
 """
 from __future__ import annotations
 
@@ -50,44 +51,72 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-LANES = 128    # TPU vector lane count
+from repro.kernels.minmax_scan import LANES, pack_lanes
 
-# Scalar input lanes.
-_IN_N = 0
-_IN_NULLS = 1
-_IN_NGROUPS = 2
-_IN_M_MIN = 3
-_IN_M_MAX = 4
-_IN_MEAN_LEN = 5
-_IN_LEN_SAMPLE = 6
-_IN_FIXED_WIDTH = 7
-_IN_INT_LIKE = 8
-_IN_SINGLE_BYTE = 9
-_IN_SCHEMA_BOUND = 10
+# Elements of one (block_b, R) plane per grid step, and the block bounds.
+PLANE_ELEMS = 1 << 13
+MIN_BLOCK_B = 8       # TPU sublane count
+MAX_BLOCK_B = 256
+# Row-group bounds: the pair terms need R >= 3 (the packer's floor is 8),
+# and past MAX_GROUPS one 8-column step outgrows the VMEM of a v5e.
+MIN_GROUPS = 8
+MAX_GROUPS = 32768
+# Widest R whose 8-column step fits the default scoped VMEM, and the
+# scoped VMEM one such step needs per that many row groups (compiled for
+# v5e: R = 16384 needs 32 MiB, R = 32768 64 MiB).
+DEFAULT_VMEM_GROUPS = 8192
+VMEM_PER_DEFAULT_GROUPS = 16 << 20
 
-# Output lanes. Lanes 9-14 carry the per-lane provenance diagnostics —
-# same exact-through-lanes properties (small ints, flags, float32) as the
-# estimate lanes, so fused provenance is bit-identical to the twin's.
-_OUT_NDV = 0
-_OUT_NDV_DICT = 1
-_OUT_NDV_MINMAX = 2
-_OUT_LAYOUT = 3
-_OUT_LOWER_BOUND = 4
-_OUT_CONFIDENCE = 5
-_OUT_OVERLAP = 6
-_OUT_MONOTONICITY = 7
-_OUT_DICT_ITERS = 8
-_OUT_ROUTE = 9
-_OUT_ROUTE_MARGIN = 10
-_OUT_DETECTOR_MARGIN = 11
-_OUT_DICT_RESIDUAL = 12
-_OUT_COUPON_ITERS = 13
-_OUT_CLAMP_FLAGS = 14
+# BatchEstimates fields carried in output lanes, in lane order, with the
+# dtype each is restored to (`mean_len` passes through from the input).
+_OUT_LANES = (
+    ("ndv", jnp.float32),
+    ("ndv_dict", jnp.float32),
+    ("ndv_minmax", jnp.float32),
+    ("layout", jnp.int32),
+    ("is_lower_bound", jnp.bool_),
+    ("confidence", jnp.float32),
+    ("overlap_ratio", jnp.float32),
+    ("monotonicity", jnp.float32),
+    ("dict_iterations", jnp.int32),
+    ("route", jnp.int32),
+    ("route_margin", jnp.float32),
+    ("detector_margin", jnp.float32),
+    ("dict_residual", jnp.float32),
+    ("coupon_iterations", jnp.int32),
+    ("clamp_flags", jnp.int32),
+)
+
+
+def block_rows(r: int) -> int:
+    """Columns per grid step at row-group width ``r``.
+
+    The largest power of two with ``block_b * r <= PLANE_ELEMS``, clamped
+    to [MIN_BLOCK_B, MAX_BLOCK_B]: 32 columns at R = 256, the floor of 8
+    from R = 1024 on.
+    """
+    b = max(PLANE_ELEMS // max(r, 1), 1)
+    b = 1 << (b.bit_length() - 1)
+    return max(MIN_BLOCK_B, min(b, MAX_BLOCK_B))
+
+
+def vmem_limit(r: int):
+    """Scoped VMEM for one grid step at width ``r`` (None: the default)."""
+    if r <= DEFAULT_VMEM_GROUPS:
+        return None
+    return VMEM_PER_DEFAULT_GROUPS * -(-r // DEFAULT_VMEM_GROUPS)
+
+
+def _from_lane(x, dtype):
+    """A lane column back to its dtype (flags were packed as 0/1)."""
+    return x > 0.5 if dtype == jnp.bool_ else x.astype(dtype)
 
 
 def _fused_body(
     mode,
+    in_dtypes,
     s_ref,
     rows_ref,
     nulls_ref,
@@ -101,58 +130,27 @@ def _fused_body(
     # Local imports: this module is imported by repro.kernels.ops, which the
     # estimator stack imports lazily — importing the stack at module scope
     # here would close the cycle.
-    from repro.core.ndv.estimator import estimate_batch_core
+    from repro.core.ndv.estimator import _PER_COLUMN, estimate_tile
     from repro.core.ndv.types import ColumnBatch
 
     scal = scal_ref[...]
+    per_column = {
+        f: _from_lane(scal[:, k:k + 1], dt)
+        for k, (f, dt) in enumerate(zip(_PER_COLUMN, in_dtypes))
+    }
     tile = ColumnBatch(
         chunk_S=s_ref[...],
         chunk_rows=rows_ref[...],
         chunk_nulls=nulls_ref[...],
         chunk_dict_encoded=dict_ref[...] > 0.5,
-        N=scal[:, _IN_N],
-        nulls=scal[:, _IN_NULLS],
-        n_groups=scal[:, _IN_NGROUPS].astype(jnp.int32),
         mins=mins_ref[...],
         maxs=maxs_ref[...],
         valid=valid_ref[...] > 0.5,
-        m_min=scal[:, _IN_M_MIN],
-        m_max=scal[:, _IN_M_MAX],
-        mean_len=scal[:, _IN_MEAN_LEN],
-        len_sample=scal[:, _IN_LEN_SAMPLE].astype(jnp.int32),
-        fixed_width=scal[:, _IN_FIXED_WIDTH] > 0.5,
-        int_like=scal[:, _IN_INT_LIKE] > 0.5,
-        single_byte=scal[:, _IN_SINGLE_BYTE] > 0.5,
+        **per_column,
     )
-    est = estimate_batch_core(
-        tile, scal[:, _IN_SCHEMA_BOUND], mode=mode, backend="ref"
-    )
-
-    out = jnp.zeros((scal.shape[0], LANES), jnp.float32)
-    out = out.at[:, _OUT_NDV].set(est.ndv)
-    out = out.at[:, _OUT_NDV_DICT].set(est.ndv_dict)
-    out = out.at[:, _OUT_NDV_MINMAX].set(est.ndv_minmax)
-    out = out.at[:, _OUT_LAYOUT].set(est.layout.astype(jnp.float32))
-    out = out.at[:, _OUT_LOWER_BOUND].set(
-        est.is_lower_bound.astype(jnp.float32)
-    )
-    out = out.at[:, _OUT_CONFIDENCE].set(est.confidence)
-    out = out.at[:, _OUT_OVERLAP].set(est.overlap_ratio)
-    out = out.at[:, _OUT_MONOTONICITY].set(est.monotonicity)
-    out = out.at[:, _OUT_DICT_ITERS].set(
-        est.dict_iterations.astype(jnp.float32)
-    )
-    out = out.at[:, _OUT_ROUTE].set(est.route.astype(jnp.float32))
-    out = out.at[:, _OUT_ROUTE_MARGIN].set(est.route_margin)
-    out = out.at[:, _OUT_DETECTOR_MARGIN].set(est.detector_margin)
-    out = out.at[:, _OUT_DICT_RESIDUAL].set(est.dict_residual)
-    out = out.at[:, _OUT_COUPON_ITERS].set(
-        est.coupon_iterations.astype(jnp.float32)
-    )
-    out = out.at[:, _OUT_CLAMP_FLAGS].set(
-        est.clamp_flags.astype(jnp.float32)
-    )
-    out_ref[...] = out
+    sb = scal[:, len(_PER_COLUMN):len(_PER_COLUMN) + 1]
+    est = estimate_tile(tile, sb, mode=mode, backend="ref")
+    out_ref[...] = pack_lanes([getattr(est, f) for f, _ in _OUT_LANES])
 
 
 @functools.partial(jax.jit, static_argnames=("mode", "interpret"))
@@ -168,65 +166,54 @@ def fused_estimate(batch, schema_bound=None, *, mode: str = "paper",
     `min(ndv, +inf)` is the identity bit-for-bit, the same trick the
     sharded engine path uses to keep one kernel signature.
     """
-    from repro.core.ndv.estimator import BatchEstimates
+    from repro.core.ndv.estimator import _PER_COLUMN, BatchEstimates
 
-    b, r = batch.chunk_S.shape
-    plane = lambda x: x.astype(jnp.float32)  # noqa: E731
+    b, r0 = batch.chunk_S.shape
+    if r0 > MAX_GROUPS:
+        raise ValueError(
+            f"{r0} row groups per column exceed the fused kernel's "
+            f"MAX_GROUPS = {MAX_GROUPS}"
+        )
+    r = max(r0, MIN_GROUPS)
+    block_b = block_rows(r)
+    pb = -(-b // block_b) * block_b
 
     if schema_bound is None:
         sb = jnp.full((b,), jnp.inf, jnp.float32)
     else:
         sb = schema_bound.astype(jnp.float32)
+    scalars = [getattr(batch, f) for f in _PER_COLUMN] + [sb]
+    scal = jnp.stack([x.astype(jnp.float32) for x in scalars], axis=1)
+    scal = jnp.pad(scal, ((0, pb - b), (0, LANES - len(scalars))))
+    planes = [
+        jnp.pad(x.astype(jnp.float32), ((0, pb - b), (0, r - r0)))
+        for x in (
+            batch.chunk_S, batch.chunk_rows, batch.chunk_nulls,
+            batch.chunk_dict_encoded, batch.mins, batch.maxs, batch.valid,
+        )
+    ]
 
-    scal = jnp.zeros((b, LANES), jnp.float32)
-    lane = lambda i, x: scal.at[:, i].set(x.astype(jnp.float32))  # noqa: E731
-    scal = lane(_IN_N, batch.N)
-    scal = lane(_IN_NULLS, batch.nulls)
-    scal = lane(_IN_NGROUPS, batch.n_groups)
-    scal = lane(_IN_M_MIN, batch.m_min)
-    scal = lane(_IN_M_MAX, batch.m_max)
-    scal = lane(_IN_MEAN_LEN, batch.mean_len)
-    scal = lane(_IN_LEN_SAMPLE, batch.len_sample)
-    scal = lane(_IN_FIXED_WIDTH, batch.fixed_width)
-    scal = lane(_IN_INT_LIKE, batch.int_like)
-    scal = lane(_IN_SINGLE_BYTE, batch.single_byte)
-    scal = scal.at[:, _IN_SCHEMA_BOUND].set(sb)
-
-    plane_spec = pl.BlockSpec((b, r), lambda i: (0, 0))
-    lane_spec = pl.BlockSpec((b, LANES), lambda i: (0, 0))
+    plane_spec = pl.BlockSpec((block_b, r), lambda i: (i, 0))
+    lane_spec = pl.BlockSpec((block_b, LANES), lambda i: (i, 0))
     out = pl.pallas_call(
-        functools.partial(_fused_body, mode),
-        out_shape=jax.ShapeDtypeStruct((b, LANES), jnp.float32),
-        grid=(1,),
+        functools.partial(
+            _fused_body, mode,
+            tuple(getattr(batch, f).dtype for f in _PER_COLUMN),
+        ),
+        out_shape=jax.ShapeDtypeStruct((pb, LANES), jnp.float32),
+        grid=(pb // block_b,),
         in_specs=[plane_spec] * 7 + [lane_spec],
         out_specs=lane_spec,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",), vmem_limit_bytes=vmem_limit(r)
+        ),
         interpret=interpret,
-    )(
-        plane(batch.chunk_S),
-        plane(batch.chunk_rows),
-        plane(batch.chunk_nulls),
-        plane(batch.chunk_dict_encoded),
-        plane(batch.mins),
-        plane(batch.maxs),
-        plane(batch.valid),
-        scal,
-    )
+    )(*planes, scal)[:b]
 
     return BatchEstimates(
-        ndv=out[:, _OUT_NDV],
-        ndv_dict=out[:, _OUT_NDV_DICT],
-        ndv_minmax=out[:, _OUT_NDV_MINMAX],
-        layout=out[:, _OUT_LAYOUT].astype(jnp.int32),
-        is_lower_bound=out[:, _OUT_LOWER_BOUND] > 0.5,
-        confidence=out[:, _OUT_CONFIDENCE],
-        overlap_ratio=out[:, _OUT_OVERLAP],
-        monotonicity=out[:, _OUT_MONOTONICITY],
         mean_len=batch.mean_len.astype(jnp.float32),
-        dict_iterations=out[:, _OUT_DICT_ITERS].astype(jnp.int32),
-        route=out[:, _OUT_ROUTE].astype(jnp.int32),
-        route_margin=out[:, _OUT_ROUTE_MARGIN],
-        detector_margin=out[:, _OUT_DETECTOR_MARGIN],
-        dict_residual=out[:, _OUT_DICT_RESIDUAL],
-        coupon_iterations=out[:, _OUT_COUPON_ITERS].astype(jnp.int32),
-        clamp_flags=out[:, _OUT_CLAMP_FLAGS].astype(jnp.int32),
+        **{
+            f: _from_lane(out[:, k], dt)
+            for k, (f, dt) in enumerate(_OUT_LANES)
+        },
     )

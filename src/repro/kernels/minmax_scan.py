@@ -88,22 +88,22 @@ def minmax_metrics_math(
     )
 
 
-def _minmax_body(mins_ref, maxs_ref, valid_ref, out_ref):
-    mins = mins_ref[...]
-    maxs = maxs_ref[...]
-    m = minmax_metrics_math(mins, maxs, valid_ref[...] > 0.5)
-    overlap, gmin, gmax = m.overlap_sum, m.gmin, m.gmax
-    changes, n, shared = m.sign_changes, m.n_valid, m.shared_bounds
+def pack_lanes(cols) -> jnp.ndarray:
+    """(b, 1) columns -> one (b, LANES) float32 tile, column k in lane k.
 
-    block_b = mins.shape[0]
-    out = jnp.zeros((block_b, LANES), jnp.float32)
-    out = out.at[:, 0].set(overlap)
-    out = out.at[:, 1].set(gmin)
-    out = out.at[:, 2].set(gmax)
-    out = out.at[:, 3].set(changes)
-    out = out.at[:, 4].set(n)
-    out = out.at[:, 5].set(shared)
-    out_ref[...] = out
+    A select against a lane iota: the TPU lowering of Pallas has no
+    scatter, so ``.at[:, k].set`` cannot pack lanes inside a kernel body.
+    """
+    lane = jax.lax.broadcasted_iota(jnp.int32, (cols[0].shape[0], LANES), 1)
+    out = jnp.zeros(lane.shape, jnp.float32)
+    for k, c in enumerate(cols):
+        out = jnp.where(lane == k, c.astype(jnp.float32), out)
+    return out
+
+
+def _minmax_body(mins_ref, maxs_ref, valid_ref, out_ref):
+    m = minmax_metrics_math(mins_ref[...], maxs_ref[...], valid_ref[...] > 0.5)
+    out_ref[...] = pack_lanes([x[:, None] for x in m])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -25,6 +25,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.ndv.dict_inversion import ceil_log2
+from repro.core.ndv.minmax_diversity import expm1
+
 DICT_ITERS = 16
 COUPON_ITERS = 40  # matches repro.core.ndv.minmax_diversity.NEWTON_ITERS
 LN2 = 0.6931471805599453
@@ -43,10 +46,6 @@ LANES = 128       # TPU vector lane count
 # tiling and padding geometry.
 
 
-def _ceil_log2(x):
-    return jnp.maximum(jnp.ceil(jnp.log2(jnp.maximum(x, 1.0)) - 1e-9), 1.0)
-
-
 def dict_newton_math(s, rows, nulls, mean_len):
     """Eq-2 fixed-iteration Newton inversion, elementwise over any shape."""
     non_null = jnp.maximum(rows - nulls, 0.0)
@@ -55,13 +54,13 @@ def dict_newton_math(s, rows, nulls, mean_len):
 
     ndv = jnp.clip(s / mean_len, 1.0, cap)
     for _ in range(DICT_ITERS):
-        f = ndv * mean_len + non_null * _ceil_log2(ndv) / 8.0 - s
+        f = ndv * mean_len + non_null * ceil_log2(ndv) / 8.0 - s
         fp = mean_len + non_null / (8.0 * jnp.maximum(ndv, 1.0) * LN2)
         ndv = jnp.clip(ndv - f / fp, 1.0, cap)
     # Plateau snap: solve the linear piece at the converged bit width.
-    bits = _ceil_log2(ndv)
+    bits = ceil_log2(ndv)
     lin = (s - non_null * bits / 8.0) / mean_len
-    keep = (_ceil_log2(jnp.maximum(lin, 1.0)) == bits) & (lin >= 1.0)
+    keep = (ceil_log2(jnp.maximum(lin, 1.0)) == bits) & (lin >= 1.0)
     return jnp.clip(jnp.where(keep, lin, ndv), 1.0, cap)
 
 
@@ -75,7 +74,7 @@ def coupon_newton_math(m, n):
     for _ in range(COUPON_ITERS):
         ndv = jnp.exp(t)
         r = n / jnp.maximum(ndv, 1e-9)
-        em1 = -jnp.expm1(-r)           # 1 - e^{-r}
+        em1 = -expm1(-r)               # 1 - e^{-r}
         g = ndv * em1 - m_eff
         gp = em1 - jnp.exp(-r) * r     # g'(D)
         t = jnp.clip(t - g / jnp.maximum(gp * ndv, 1e-12), 0.0, 28.0)

@@ -52,7 +52,7 @@ def use_fused(fuse: str) -> bool:
     exactly where fusing buys anything — on TPU, where the separate path
     costs 3-4 kernel launches plus XLA glue per estimate. The fused pipeline
     computes the REFERENCE numerics (`fused_estimate`'s body runs
-    `estimate_batch_core(..., backend="ref")`), and `fused_estimate` below
+    `estimate_tile(..., backend="ref")`), and `fused_estimate` below
     only compiles the kernel where the kernel path is the production path —
     elsewhere the pure-XLA twin runs, which is the same program as the
     unfused reference path. That is why the knob is numerics-neutral and

@@ -76,8 +76,8 @@ def ref_fused_estimate(batch, schema_bound=None, *, mode: str = "paper"):
     """Oracle for fused_estimate.fused_estimate — the same core, no kernel.
 
     The megakernel body runs the reference pipeline
-    (``estimate_batch_core(..., backend="ref")``) on its tile refs; this
-    twin runs the identical call outside any kernel, materializing the
+    (``estimate_tile(..., backend="ref")``) on its tile refs; this twin
+    runs it outside any kernel through `estimate_batch_core`, materializing the
     absent schema bound as +inf the same way the kernel wrapper does. It is
     also the off-TPU serving path for ``fuse="on"`` (see `ops.fused_estimate`),
     which is what makes the fuse knob bit-neutral there by construction.

@@ -29,6 +29,7 @@ import tempfile
 import time
 import urllib.error
 
+from repro.compile_cache import enable_compile_cache
 from repro.engine import EngineConfig
 from repro.fleet import (
     DatasetRegistry,
@@ -78,7 +79,7 @@ def build_parser() -> argparse.ArgumentParser:
     return ap
 
 
-def _engine_config(args: argparse.Namespace) -> EngineConfig:
+def engine_config(args: argparse.Namespace) -> EngineConfig:
     mb = args.max_batch
     return EngineConfig(
         strategy=args.strategy,
@@ -87,7 +88,7 @@ def _engine_config(args: argparse.Namespace) -> EngineConfig:
     )
 
 
-def _make_router(args: argparse.Namespace, registry: DatasetRegistry) -> StatsRouter:
+def make_router(args: argparse.Namespace, registry: DatasetRegistry) -> StatsRouter:
     fleet = Fleet(
         registry,
         replicas_per_dataset=args.replicas,
@@ -131,12 +132,12 @@ def run_smoke(args: argparse.Namespace) -> int:
     })
     base = tempfile.mkdtemp()
     registry = DatasetRegistry()
-    cfg = _engine_config(args)
+    cfg = engine_config(args)
     for name, seed in (("alpha", 1), ("beta", 2)):
         root = _smoke_dataset(os.path.join(base, name), seed)
         registry.add("smoke", name, root, engine_config=cfg)
 
-    with _make_router(args, registry) as router:
+    with make_router(args, registry) as router:
         base_url = router.url
         # both datasets serve through one endpoint
         etags = {}
@@ -315,6 +316,7 @@ def run_smoke(args: argparse.Namespace) -> int:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
     if args.smoke:
         return run_smoke(args)
     if not args.dataset:
@@ -322,11 +324,11 @@ def main(argv=None) -> int:
               "(or use --smoke)", file=sys.stderr)
         return 2
     registry = DatasetRegistry()
-    cfg = _engine_config(args)
+    cfg = engine_config(args)
     for spec in args.dataset:
         ns, ds, root = parse_spec(spec)
         registry.add(ns, ds, root, engine_config=cfg)
-    with _make_router(args, registry) as router:
+    with make_router(args, registry) as router:
         print(f"[serve_fleet] routing {len(registry)} datasets x "
               f"{args.replicas} replicas at {router.url}")
         for key in registry.keys():

@@ -23,6 +23,7 @@ import tempfile
 import time
 import urllib.error
 
+from repro.compile_cache import enable_compile_cache
 from repro.engine import EngineConfig, EstimationEngine
 from repro.service import StatsServer, StatsService, fetch_json
 from repro.wire import ConnectionPool, fetch
@@ -61,7 +62,7 @@ def build_parser() -> argparse.ArgumentParser:
     return ap
 
 
-def _make_server(args: argparse.Namespace, root: str) -> StatsServer:
+def make_server(args: argparse.Namespace, root: str) -> StatsServer:
     engine = EstimationEngine(
         EngineConfig(strategy=args.strategy, backend=args.backend)
     )
@@ -106,7 +107,7 @@ def run_smoke(args: argparse.Namespace) -> int:
     args = argparse.Namespace(**{**vars(args), "port": 0,
                                  "refresh_interval": 0.0, "audit": True})
     root = args.root or _smoke_dataset()
-    with _make_server(args, root) as server:
+    with make_server(args, root) as server:
         base = server.url
         status, etag, body = fetch_json(base + "/estimate?mode=improved")
         assert status == 200 and etag and body["estimates"], (status, body)
@@ -204,12 +205,13 @@ def run_smoke(args: argparse.Namespace) -> int:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
     if args.smoke:
         return run_smoke(args)
     if not args.root:
         print("error: --root is required (or use --smoke)", file=sys.stderr)
         return 2
-    with _make_server(args, args.root) as server:
+    with make_server(args, args.root) as server:
         print(f"[serve_stats] serving {args.root} at {server.url} "
               f"(engine {server.service.engine.cache_token}, "
               f"refresh every {args.refresh_interval or 'never'}s)")

@@ -555,6 +555,46 @@ def test_engine_config_auto_max_batch_validation():
         EngineConfig(max_batch="turbo")
 
 
+class _FakeDevice:
+    def __init__(self, platform, stats):
+        self.platform = platform
+        self.device_kind = f"fake {platform}"
+        self._stats = stats
+
+    def memory_stats(self):
+        if isinstance(self._stats, Exception):
+            raise self._stats
+        return self._stats
+
+
+@pytest.mark.parametrize(
+    "platform,stats,expect",
+    [
+        ("cpu", None, None),
+        ("tpu", {"bytes_limit": 16 * 2**30}, 16 * 2**30),
+        ("tpu", {"bytes_reservable_limit": 2**33}, 2**33),
+        ("tpu", None, RuntimeError),
+        ("tpu", {}, RuntimeError),
+        ("tpu", OSError("allocator gone"), OSError),
+    ],
+)
+def test_detect_device_memory_never_hides_an_accelerator(
+    monkeypatch, platform, stats, expect
+):
+    # Only the host CPU may report "unknown"; on an accelerator a failed or
+    # empty report raises instead of shrinking the auto budget.
+    from repro.engine import engine as engine_mod
+
+    monkeypatch.setattr(
+        engine_mod.jax, "local_devices", lambda: [_FakeDevice(platform, stats)]
+    )
+    if isinstance(expect, type) and issubclass(expect, Exception):
+        with pytest.raises(expect):
+            engine_mod.detect_device_memory()
+    else:
+        assert engine_mod.detect_device_memory() == expect
+
+
 def test_resolve_max_batch_auto_detects_once(monkeypatch):
     from repro.engine import engine as engine_mod
 
