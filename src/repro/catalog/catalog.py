@@ -568,6 +568,8 @@ class StatsCatalog:
         arr = self.bounds_array(schema_bounds, batch.batch)
         sb = None if arr is None else jnp.asarray(arr)
         out = engine.estimate(batch, sb, mode=mode)
+        with _obs_span("engine.device_wait"):
+            jax.block_until_ready(out)
         with _obs_span("engine.d2h", columns=len(self._column_names)):
             ests = estimates_from_batch(out, batch, self._column_names)
             provs = provenance_from_batch(out, batch, self._column_names)
@@ -638,6 +640,8 @@ class StatsCatalog:
         arr = self.bounds_array(schema_bounds, batch.batch)
         sb = None if arr is None else jnp.asarray(arr)
         out = engine.estimate(batch, sb, mode=mode)
+        with _obs_span("engine.device_wait"):
+            jax.block_until_ready(out)
         with _obs_span("engine.d2h", columns=len(self._column_names)):
             ests = estimates_from_batch(out, batch, self._column_names)
             provs = provenance_from_batch(out, batch, self._column_names)

@@ -25,6 +25,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.ndv.types import ColumnBatch, ColumnMetadata, PhysicalType
+from repro.obs import registry, span
+
+_PACK_CELLS = registry().counter(
+    "ndv_pack_cells_total",
+    "(column, row group) cells of every pack built: real or padded B x R",
+)
+_REAL_CELLS = _PACK_CELLS.labels(cell="real")
+_PADDED_CELLS = _PACK_CELLS.labels(cell="padded")
 
 # Per-PhysicalType lookup tables, indexed by the enum value.
 _N_TYPES = max(int(t) for t in PhysicalType) + 1
@@ -136,12 +144,18 @@ class BatchPacker:
 
     def pack(self, cols: Sequence[ColumnMetadata]) -> ColumnBatch:
         """Pack per-column metadata into a padded struct-of-arrays batch."""
+        with span("catalog.pack", columns=len(cols)):
+            return self._pack(cols)
+
+    def _pack(self, cols: Sequence[ColumnMetadata]) -> ColumnBatch:
         nb = len(cols)
         n_per = np.fromiter((c.num_row_groups for c in cols), np.int64, count=nb)
         max_r = int(n_per.max()) if nb else 1
         B, R = self.shape_for(nb, max_r)
 
         total = int(n_per.sum())
+        _REAL_CELLS.inc(total)
+        _PADDED_CELLS.inc(B * R)
         # Flat chunk layout: chunk j of column i lands at plane[(i, j)].
         row_idx = np.repeat(np.arange(nb), n_per)
         starts = np.zeros(nb, np.int64)

@@ -29,6 +29,7 @@ from __future__ import annotations
 
 from typing import Dict, List, NamedTuple, Optional
 
+import jax
 import jax.numpy as jnp
 
 from repro.catalog.packer import concat_batches
@@ -137,6 +138,8 @@ def _run_group(eng, members: List[_ColdJob], results: list) -> None:
         sb = jnp.asarray(arr)
 
     out = eng.estimate(batch, sb, mode=mode)
+    with _obs_span("engine.device_wait"):
+        jax.block_until_ready(out)
     with _obs_span("engine.d2h", jobs=len(members), batch=int(batch.batch)):
         for m, off in zip(members, offsets):
             names = m.job.catalog.column_names

@@ -48,7 +48,14 @@ The config also carries the `kernels/ops` backend knob ("auto" / "pallas" /
 "ref"), which used to be unreachable from the public API: the engine threads
 it into `estimate_batch`, which routes the Newton inversions and the
 detector scan through the Pallas kernels or the jnp reference accordingly.
+
+Importing this package installs the `repro.obs` profiler bridge: while a
+`jax.profiler` session collects, every program span is also a host
+TraceMe of the same name, on the clock of the device's ops.
 """
+import jax
+
+from repro.obs import trace as _trace
 from repro.engine.config import DEFAULT_MAX_BATCH, EngineConfig  # noqa: F401
 from repro.engine.engine import (  # noqa: F401
     EstimationEngine,
@@ -58,3 +65,5 @@ from repro.engine.engine import (  # noqa: F401
     default_packer,
     detect_device_memory,
 )
+
+_trace.set_profiler_bridge(jax.profiler.TraceAnnotation)

@@ -314,6 +314,8 @@ class EstimationEngine:
         """
         strategy = self.resolve_strategy(batch.batch)
         _DISPATCHES.inc(strategy=strategy, mode=mode)
+        # The enqueue only: JAX dispatches asynchronously, so the device's
+        # time lands in the caller's `engine.device_wait` span.
         with _obs_span(
             "engine.dispatch",
             strategy=strategy, mode=mode, batch=int(batch.batch),

@@ -3,19 +3,39 @@
 Stdlib-only (no jax, no other repro imports), so every tier can depend
 on it without layering cycles. Two halves behind one kill-switch:
 
-    client ──POST /batch──────────────▶ StatsRouter        (root span)
+    client ──POST /cost───────────────▶ StatsRouter   (root router.cost)
                                           │  traceparent: header + wire
                                           │                 frame section
                   ┌───────────────────────┴──────────────┐
                   ▼                                      ▼
-            replica A  (replica.sub_batch)         replica B
-                  │                                      │
-            StatsService.batch (service.superpack)       │
-                  │                                      │
-            EstimationEngine  (engine.pack → engine.dispatch → engine.d2h)
-                  │
-          spans close bottom-up → each lands in the bounded finished-span
-          ring → grouped per trace at GET /debug/traces?limit=N (JSON trees)
+            replica.call  (one per table)          replica B (HTTP,
+                  │                                 its own root)
+            service.request ─┬─ service.lock_wait
+                             ├─ service.flight_wait   (a follower)
+                             └─ service.compute
+                                  ├─ engine.pack ── catalog.pack
+                                  ├─ engine.h2d
+                                  ├─ engine.dispatch     (the enqueue)
+                                  ├─ engine.device_wait  (the device)
+                                  └─ engine.d2h
+            service.superpack  (POST /batch: the engine.* spans above)
+            planner.compute_cost ─┬─ planner.enumerate
+                                  ├─ planner.score ── planner.fold
+                                  └─ planner.pick
+            ingest.refresh ─┬─ ingest.lock_wait       (no root needed)
+                            └─ catalog.merge
+
+          spans of a trace close bottom-up → each lands in the bounded
+          finished-span ring → grouped per trace at GET /debug/traces?limit=N
+          (JSON trees); service.request and service.flight_wait are
+          `timed_span`s, kept out of the ring (their children hang off
+          the root), so a warm 304's trace stays childless and is
+          dropped. Every span but an HTTP root, in a trace or not, feeds
+          ndv_span_seconds{span=} and ndv_span_self_seconds_total{span=}
+          (its time minus its children's); while a jax.profiler session
+          collects, every span, roots included, is a host TraceMe of the
+          same name (the bridge `repro.engine` installs; this package
+          imports no jax).
 
     Counters / gauges / histograms land in the process-global
     `MetricsRegistry`; pre-existing stats objects (`ServiceStats`,
@@ -28,8 +48,12 @@ on it without layering cycles. Two halves behind one kill-switch:
 Telemetry is NEUTRAL by contract: nothing here enters `cache_key`,
 `cache_token`, or ETag derivation — estimate bytes and ETags are
 byte-identical with telemetry on or off (`set_enabled(False)` turns
-every increment and span into a no-op; `benchmarks/obs_overhead.py`
-holds the warm-path overhead under 5%).
+every increment and span into a no-op). On a TPU v5e host a span costs
+about 3.0 us in a trace and 2.4 us outside one in a tight loop, and
+about 3.5 times that inside a served request. `benchmarks/obs_overhead.py`
+(warm /estimate, telemetry on against off; asserts < 5% in full mode)
+read 4.55% and 5.2% there, and the revalidation-bound static TPC-H cell
+gives up about 4.4% of its probes per second to the spans (PERF.md).
 
 Estimation-quality observability rides the same registry. Every batch
 the estimator runs also emits per-lane PROVENANCE (core/ndv: route
@@ -73,6 +97,7 @@ from repro.obs.metrics import (
     registry,
 )
 from repro.obs.trace import (
+    SPAN_NAMES,
     Span,
     TRACEPARENT_HEADER,
     TraceCollector,
@@ -82,7 +107,10 @@ from repro.obs.trace import (
     format_traceparent,
     parse_traceparent,
     root_span,
+    set_profiler_bridge,
     span,
+    timed_acquire,
+    timed_span,
     trace_tree,
 )
 
@@ -92,6 +120,7 @@ __all__ = [
     "Histogram",
     "LATENCY_BUCKETS_S",
     "MetricsRegistry",
+    "SPAN_NAMES",
     "Span",
     "TRACEPARENT_HEADER",
     "TraceCollector",
@@ -105,7 +134,10 @@ __all__ = [
     "registry",
     "root_span",
     "set_enabled",
+    "set_profiler_bridge",
     "span",
+    "timed_acquire",
+    "timed_span",
     "trace_tree",
 ]
 

@@ -207,6 +207,36 @@ class _BoundHistogram:
                 cell.counts[idx] += 1
 
 
+class _BoundTimer:
+    """A histogram of durations and a counter of seconds pre-resolved to
+    ONE label set, so to one stripe lock (`Histogram.timer(...)`).
+
+    `record(duration, seconds)` is `observe(duration)` plus `inc(seconds)`
+    under a single acquisition: the per-span call site (`repro.obs.trace`)
+    runs several times a request, where a second lock round-trip shows.
+    """
+
+    __slots__ = ("_h", "_c", "_buckets")
+
+    def __init__(self, hist: _HistCell, total: _Cell,
+                 buckets: Tuple[float, ...]):
+        self._h = hist
+        self._c = total
+        self._buckets = buckets
+
+    def record(self, duration: float, seconds: float) -> None:
+        if not _state.enabled:
+            return
+        cell = self._h
+        idx = bisect.bisect_left(self._buckets, duration)
+        with cell.lock:  # the counter cell's lock too (see Histogram.timer)
+            cell.count += 1
+            cell.sum += duration
+            if idx < len(self._buckets):
+                cell.counts[idx] += 1
+            self._c.value += seconds
+
+
 class Counter(_Metric):
     kind = "counter"
 
@@ -268,6 +298,14 @@ class Histogram(_Metric):
 
     def labels(self, **labels) -> _BoundHistogram:
         return _BoundHistogram(self._cell(labels), self.buckets)
+
+    def timer(self, total: Counter, **labels) -> _BoundTimer:
+        """Bind this histogram and the counter `total` of the same registry
+        to one label set; the two cells then share a stripe lock."""
+        hist, cell = self._cell(labels), total._cell(labels)
+        if hist.lock is not cell.lock:
+            raise ValueError("timer: histogram and counter in two registries")
+        return _BoundTimer(hist, cell, self.buckets)
 
 
 class _StatsView:

@@ -16,7 +16,6 @@ pass-through, the conservative choice). Unknown columns raise
 """
 from __future__ import annotations
 
-import time
 from typing import Dict, List, NamedTuple, Optional
 
 import numpy as np
@@ -24,7 +23,6 @@ import numpy as np
 from repro.obs import span
 from repro.planner.cost import (
     best_plan_index,
-    observe_cost_ms,
     reference_cost,
     score_plans,
 )
@@ -72,9 +70,22 @@ def compute_cost(
 
     `stats` maps each graph table NAME (the alias, not the dataset key)
     to its `TableStats`. Raises `ValueError` for resolvable-to-400
-    problems (missing stats for a referenced table/column).
+    problems (missing stats for a referenced table/column). The whole
+    call is the `planner.compute_cost` span; its children are
+    `planner.enumerate`, `planner.score` (with `planner.fold`) and
+    `planner.pick`.
     """
-    t0 = time.perf_counter()
+    with span("planner.compute_cost", tables=len(graph.tables)):
+        return _cost_body(graph, stats, mode, max_plans, explain)
+
+
+def _cost_body(
+    graph: JoinGraph,
+    stats: Dict[str, TableStats],
+    mode: str,
+    max_plans: int,
+    explain: bool,
+) -> dict:
     names = graph.names
     n = len(names)
     index = {name: i for i, name in enumerate(names)}
@@ -112,7 +123,8 @@ def compute_cost(
         plans = enumerate_plans(n, max_plans)
     with span("planner.score", plans=int(plans.shape[0]), tables=n):
         costs, step_cards = score_plans(plans, base_rows, factors)
-    best = best_plan_index(plans, costs)
+    with span("planner.pick", plans=int(plans.shape[0])):
+        best = best_plan_index(plans, costs)
     best_plan = [int(x) for x in plans[best]]
     best_order = [names[i] for i in best_plan]
 
@@ -159,7 +171,6 @@ def compute_cost(
     }
     if explain:
         body["provenance"] = provenance_block(graph, stats)
-    observe_cost_ms((time.perf_counter() - t0) * 1000.0)
     return body
 
 

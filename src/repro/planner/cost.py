@@ -28,8 +28,8 @@ output) so XLA cannot contract the multiply into an FMA with the cost
 add.
 
 Metrics (`repro.obs` registry): `planner_plans_scored_total`,
-`planner_dispatches_total`, `planner_cost_ms`; the serving layer wraps
-calls in `planner.enumerate` / `planner.score` spans.
+`planner_dispatches_total`; `score_plans` times its device fold and the
+reads of its results in a `planner.fold` span.
 """
 from __future__ import annotations
 
@@ -41,21 +41,14 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.obs import registry
+from repro.obs import registry, span
 
 __all__ = [
-    "COST_MS_BUCKETS",
     "EdgeFactor",
     "best_plan_index",
     "reference_cost",
     "score_plans",
 ]
-
-# /cost scoring wall-time (milliseconds — the series is planner_cost_ms):
-# sub-ms warm small graphs through cold-trace hundreds of ms.
-COST_MS_BUCKETS = (
-    0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 25.0, 50.0, 100.0, 250.0, 500.0, 2500.0,
-)
 
 _PLANS_SCORED = registry().counter(
     "planner_plans_scored_total",
@@ -65,19 +58,9 @@ _DISPATCHES = registry().counter(
     "planner_dispatches_total",
     "Batched plan-scoring dispatches (one per cold /cost computation)",
 )
-_COST_MS = registry().histogram(
-    "planner_cost_ms",
-    "End-to-end /cost plan scoring wall time (milliseconds)",
-    buckets=COST_MS_BUCKETS,
-)
 
 #: (left_table_index, right_table_index, float32 selectivity multiplier).
 EdgeFactor = Tuple[int, int, float]
-
-
-def observe_cost_ms(ms: float) -> None:
-    """Record one end-to-end scoring wall time (serving layer calls this)."""
-    _COST_MS.observe(float(ms))
 
 
 def _pow2_at_least(n: int) -> int:
@@ -171,11 +154,12 @@ def score_plans(
         mults = np.pad(mults, pad, constant_values=1.0)
 
     fold = _scan_fold(n, p_pad)
-    cost, cards = fold(jnp.asarray(rows), jnp.asarray(mults))
+    with span("planner.fold", lanes=p_pad):
+        cost, cards = fold(jnp.asarray(rows), jnp.asarray(mults))
+        costs = np.asarray(cost)[:p]
+        step_cards = np.asarray(cards).T[:p]  # (n-1, p_pad) -> (P, n-1)
     _DISPATCHES.inc()
     _PLANS_SCORED.inc(p)
-    costs = np.asarray(cost)[:p]
-    step_cards = np.asarray(cards).T[:p]  # (n-1, p_pad) -> (P, n-1)
     return costs, step_cards
 
 

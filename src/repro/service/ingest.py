@@ -33,7 +33,7 @@ import time
 from typing import Callable, List, Optional, Tuple
 
 from repro.catalog import FileEntry, StatsCatalog, UpdateSummary
-from repro.obs import span
+from repro.obs import span, timed_acquire
 
 
 @dataclasses.dataclass
@@ -109,15 +109,19 @@ class AsyncIngestor:
                 # a reader must never observe the new merged state paired
                 # with a pre-commit generation/ETag (the serving layer
                 # rotates its state token inside on_commit).
-                with self.lock:
-                    summary = self.catalog.apply_footers(
-                        fresh, live_ids=live_ids
-                    )
+                timed_acquire(self.lock, "ingest.lock_wait")
+                try:
+                    with span("catalog.merge", footers=len(fresh)):
+                        summary = self.catalog.apply_footers(
+                            fresh, live_ids=live_ids
+                        )
                     if summary.changed:
                         self.generation += 1
                         self.stats.commits += 1
                         if self.on_commit is not None:
                             self.on_commit(summary)
+                finally:
+                    self.lock.release()
             except Exception as e:
                 self.stats.errors += 1
                 self.stats.last_error = f"{type(e).__name__}: {e}"

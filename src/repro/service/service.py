@@ -51,7 +51,7 @@ from repro.catalog import (
 )
 from repro.catalog.source import MetadataSource
 from repro.core.ndv.estimator import provenance_to_json
-from repro.obs import registry, span
+from repro.obs import registry, span, timed_acquire, timed_span
 from repro.obs.metrics import QERROR_BUCKETS
 from repro.planner import (
     ColumnStats,
@@ -197,7 +197,8 @@ class SingleFlight:
     @staticmethod
     def wait(call: _Call) -> object:
         """Block on a follower's call; re-raises the leader's exception."""
-        call.event.wait()
+        with timed_span("service.flight_wait"):
+            call.event.wait()
         if call.error is not None:
             raise call.error
         return call.result
@@ -1123,52 +1124,70 @@ class StatsService:
         """The shared cacheable-endpoint skeleton (ETag precheck,
         single-flight, lock discipline). `ident_key` is whatever request
         identity the endpoint hashes besides kind/mode — schema bounds for
-        the estimate family, (graph identity, max_plans) for `/cost`."""
-        self.stats.requests += 1
-        if mode not in MODES:
-            return Response(
-                400, {"error": f"mode {mode!r} not in {list(MODES)}"}, None
-            )
-        self._ensure_ready()
-        etag = self._etag(kind, mode, ident_key, columns)
-        if if_none_match is not None and etag_matches(if_none_match, etag):
-            # The entire hit path: one lock-free digest. No pack, no engine.
-            self.stats.responses_304 += 1
-            return Response(304, None, etag)
+        the estimate family, (graph identity, max_plans) for `/cost`.
 
-        def compute() -> dict:
-            with self.lock:
-                # Recompute the tag inside the lock: a refresh may have
-                # committed since the cheap pre-check, and the body must
-                # describe the state its ETag names.
-                etag_now = self._etag(kind, mode, ident_key, columns)
-                if self.shared_spill:
-                    # A sibling replica may have computed (and spilled)
-                    # this entry already: one stat when nothing changed,
-                    # and a cache line instead of an engine run when it did.
-                    self.stats.spill_reloads += bool(
-                        self.catalog.maybe_load_cache()
-                    )
-                misses = self.catalog.stats.estimate_cache_misses
-                with span(
-                    "service.compute",
-                    kind=kind, mode=mode, service=self.name,
-                ):
-                    body = build(etag_now, self.ingestor.generation)
-                new_runs = (
-                    self.catalog.stats.estimate_cache_misses - misses
+        Spans: `service.request` is the whole call; its self time is the
+        state digest, the 304 check and the bookkeeping. Its children are
+        `service.lock_wait` (queued behind another request or a refresh
+        commit), `service.compute` (the body build under the lock) and,
+        for a coalesced request, `service.flight_wait`. The request and
+        the flight wait are `timed_span`s: they stay out of the trace
+        ring, so a 304's trace is dropped as a childless root."""
+        with timed_span("service.request"):
+            self.stats.requests += 1
+            if mode not in MODES:
+                return Response(
+                    400, {"error": f"mode {mode!r} not in {list(MODES)}"},
+                    None,
                 )
-                self.stats.engine_runs += new_runs
-                if new_runs and self.save_cache_on_commit:
-                    # the spill must include what was just computed, or a
-                    # restart between now and the next commit starts cold
-                    self.catalog.save_cache()
-                return body
+            self._ensure_ready()
+            etag = self._etag(kind, mode, ident_key, columns)
+            if if_none_match is not None and etag_matches(
+                if_none_match, etag
+            ):
+                # The entire hit path: one lock-free digest. No pack, no
+                # engine.
+                self.stats.responses_304 += 1
+                return Response(304, None, etag)
 
-        body, leader = self._flight.do((kind, etag), compute)
-        if leader:
-            self.stats.single_flight_leaders += 1
-        else:
-            self.stats.coalesced_waits += 1
-        self.stats.responses_200 += 1
-        return Response(200, body, body["etag"])
+            def compute() -> dict:
+                timed_acquire(self.lock, "service.lock_wait")
+                try:
+                    # Recompute the tag inside the lock: a refresh may have
+                    # committed since the cheap pre-check, and the body must
+                    # describe the state its ETag names.
+                    etag_now = self._etag(kind, mode, ident_key, columns)
+                    if self.shared_spill:
+                        # A sibling replica may have computed (and
+                        # spilled) this entry already: one stat when
+                        # nothing changed, and a cache line instead of an
+                        # engine run when it did.
+                        self.stats.spill_reloads += bool(
+                            self.catalog.maybe_load_cache()
+                        )
+                    misses = self.catalog.stats.estimate_cache_misses
+                    with span(
+                        "service.compute",
+                        kind=kind, mode=mode, service=self.name,
+                    ):
+                        body = build(etag_now, self.ingestor.generation)
+                    new_runs = (
+                        self.catalog.stats.estimate_cache_misses - misses
+                    )
+                    self.stats.engine_runs += new_runs
+                    if new_runs and self.save_cache_on_commit:
+                        # the spill must include what was just computed,
+                        # or a restart between now and the next commit
+                        # starts cold
+                        self.catalog.save_cache()
+                    return body
+                finally:
+                    self.lock.release()
+
+            body, leader = self._flight.do((kind, etag), compute)
+            if leader:
+                self.stats.single_flight_leaders += 1
+            else:
+                self.stats.coalesced_waits += 1
+            self.stats.responses_200 += 1
+            return Response(200, body, body["etag"])

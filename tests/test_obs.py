@@ -21,6 +21,7 @@ import os
 import re
 import threading
 import time
+import urllib.request
 from dataclasses import dataclass
 
 import numpy as np
@@ -127,6 +128,21 @@ def test_bound_handles_write_same_cells():
     text = reg.exposition()
     assert 'bh_count{route="x"} 2' in text
     assert 'bh_bucket{route="x",le="1"} 1' in text
+
+
+def test_bound_timer_writes_both_cells():
+    reg = MetricsRegistry()
+    c = reg.counter("t_total")
+    h = reg.histogram("th", buckets=(1.0,))
+    h.timer(c, route="x").record(0.5, 0.25)
+    h.labels(route="x").observe(2.0)
+    c.labels(route="x").inc(1)
+    assert c.value(route="x") == 1.25
+    text = reg.exposition()
+    assert 'th_count{route="x"} 2' in text and 'th_sum{route="x"} 2.5' in text
+    assert 'th_bucket{route="x",le="1"} 1' in text
+    with pytest.raises(ValueError):
+        h.timer(MetricsRegistry().counter("t_total"), route="x")
 
 
 def test_exposition_escapes_hostile_labels_and_obeys_grammar():
@@ -522,3 +538,324 @@ def test_etag_neutral_to_telemetry_state(dataset):
     assert json.dumps(body_off, sort_keys=True) == json.dumps(
         body_on, sort_keys=True
     )
+
+
+# -- span series and the profiler bridge ---------------------------------------
+
+
+def _span_stats(name):
+    """(count, seconds, self seconds) recorded so far for span `name`."""
+    key = (("span", name),)
+    reg = obs.registry()
+    hist = dict(reg.histogram("ndv_span_seconds").snapshot()).get(key)
+    own = dict(reg.counter("ndv_span_self_seconds_total").snapshot()).get(key)
+    return (
+        hist.count if hist is not None else 0,
+        hist.sum if hist is not None else 0.0,
+        own.value if own is not None else 0.0,
+    )
+
+
+def test_span_self_time_excludes_children():
+    before_p, before_c = _span_stats("t.parent"), _span_stats("t.child")
+    with obs.root_span("t.root"):
+        with obs.span("t.parent"):
+            time.sleep(0.02)
+            with obs.span("t.child"):
+                time.sleep(0.05)
+    p, c = _span_stats("t.parent"), _span_stats("t.child")
+    p_n, p_s, p_own = (a - b for a, b in zip(p, before_p))
+    c_n, c_s, c_own = (a - b for a, b in zip(c, before_c))
+    assert (p_n, c_n) == (1, 1)  # the histogram counts both
+    assert c_s >= 0.05 and c_own == pytest.approx(c_s)  # a leaf owns it all
+    assert p_s >= 0.07
+    assert p_own == pytest.approx(p_s - c_s, abs=1e-9)
+    assert 0.02 <= p_own < 0.05  # the child's sleep is not the parent's
+
+
+def test_rootless_span_is_timed_but_not_traced():
+    col = obs.collector()
+    before = _span_stats("t.rootless")
+    with obs.span("t.rootless") as sp:
+        assert sp.trace_id is None and sp.traceparent is None
+        assert obs.current_span() is None  # no trace to propagate
+        assert obs.current_traceparent() is None
+        with obs.span("t.rootless_kid") as kid:
+            assert kid.trace_id is None
+    n, secs, own = (a - b for a, b in zip(_span_stats("t.rootless"), before))
+    assert n == 1 and secs > 0 and 0 < own <= secs
+    assert col.traces() == []  # the ring only holds spans of a trace
+
+
+class _SpyBridge:
+    """Profiler bridge stand-in: counts enters, never collects."""
+
+    entered = 0
+    on = True
+
+    @classmethod
+    def is_enabled(cls):
+        return cls.on
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        type(self).entered += 1
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+@pytest.fixture()
+def spy_bridge():
+    from repro.obs import trace
+
+    saved = trace._bridge
+    _SpyBridge.entered, _SpyBridge.on = 0, True
+    trace.set_profiler_bridge(_SpyBridge)
+    yield _SpyBridge
+    trace.set_profiler_bridge(saved)
+
+
+def test_disabled_spans_record_nothing_and_skip_the_bridge(spy_bridge):
+    before = _span_stats("t.off")
+    obs.set_enabled(False)
+    with obs.root_span("t.off"):
+        with obs.span("t.off"):
+            pass
+    obs.set_enabled(True)
+    assert _span_stats("t.off") == before
+    assert spy_bridge.entered == 0
+    with obs.span("t.off"):
+        pass
+    assert spy_bridge.entered == 1
+    spy_bridge.on = False  # no profiler session: the bridge stays shut
+    with obs.span("t.off"):
+        pass
+    assert spy_bridge.entered == 1
+
+
+def test_profiler_bridge_puts_spans_on_the_host_plane(tmp_path):
+    import glob
+
+    import jax
+
+    import repro.engine  # noqa: F401  (installs the bridge)
+    from repro.obs import trace
+
+    assert trace._bridge is jax.profiler.TraceAnnotation
+
+    class Counting(jax.profiler.TraceAnnotation):
+        made = 0
+
+        def __init__(self, name, **kw):
+            type(self).made += 1
+            super().__init__(name, **kw)
+
+    trace.set_profiler_bridge(Counting)
+    try:
+        with obs.span("t.unprofiled"):
+            pass
+        assert Counting.made == 0  # no session: is_enabled() is False
+        with jax.profiler.trace(str(tmp_path)):
+            with obs.span("t.bridged"):
+                time.sleep(0.001)
+        assert Counting.made == 1
+    finally:
+        trace.set_profiler_bridge(jax.profiler.TraceAnnotation)
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    data = jax.profiler.ProfileData.from_file(path)
+    host = {
+        e.name
+        for plane in data.planes if plane.name.startswith("/host:")
+        for line in plane.lines for e in line.events
+    }
+    assert "t.bridged" in host and "t.unprofiled" not in host
+
+
+def test_bridge_leaves_lowered_programs_unchanged(spy_bridge):
+    import jax.numpy as jnp
+
+    from repro.catalog import StatsCatalog
+    from repro.catalog.packer import BatchPacker
+    from repro.catalog.source import InMemoryMetadataSource
+    from repro.columnar.lakehouse import LakehouseShape, synthesize_lakehouse
+    from repro.core.ndv.estimator import estimate_batch
+    from repro.obs import trace
+    from repro.planner.cost import _scan_fold
+
+    footers, _, _ = synthesize_lakehouse(0, LakehouseShape(
+        tables=1, columns_per_table=4, files=2, row_groups_per_file=4,
+    ))
+    cat = StatsCatalog(InMemoryMetadataSource(footers))
+    batch = BatchPacker().pack(list(cat.merged_metadata().values()))
+    ones = jnp.ones((8, 3), jnp.float32)
+
+    def lowered():
+        with obs.span("engine.dispatch"), obs.span("planner.fold"):
+            return (
+                estimate_batch.lower(batch, None, mode="paper").as_text(),
+                _scan_fold(3, 8).lower(ones, ones).as_text(),
+            )
+
+    spy_bridge.entered = 0
+    with_bridge = lowered()
+    assert spy_bridge.entered == 2
+    trace.set_profiler_bridge(None)
+    assert lowered() == with_bridge
+
+
+def test_engine_device_wait_once_per_dispatch():
+    from repro.catalog import StatsCatalog
+    from repro.catalog.source import InMemoryMetadataSource
+    from repro.columnar.lakehouse import LakehouseShape, synthesize_lakehouse
+
+    def dispatches():
+        return sum(
+            cell.value for _, cell in
+            obs.registry().counter("ndv_engine_dispatches_total").snapshot()
+        )
+
+    footers, _, _ = synthesize_lakehouse(1, LakehouseShape(
+        tables=1, columns_per_table=4, files=2, row_groups_per_file=4,
+    ))
+    cat = StatsCatalog(InMemoryMetadataSource(footers))
+    waits, calls = _span_stats("engine.device_wait")[0], dispatches()
+    cat.estimate(mode="paper")
+    cat.estimate(mode="improved")
+    cat.estimate(mode="paper")  # cached: no dispatch, no wait
+    assert dispatches() - calls == 2
+    assert _span_stats("engine.device_wait")[0] - waits == 2
+
+
+def test_span_series_exported_and_tablestats_cost_neutral(dataset):
+    graph = {
+        "tables": [{"name": "a"}, {"name": "b"}],
+        "edges": [{"left": "a", "left_column": "tok",
+                   "right": "b", "right_column": "tok"}],
+    }
+
+    def serve():
+        with StatsServer(StatsService(dataset)) as srv:
+            got = [
+                fetch_json(srv.url + "/tablestats?mode=paper"),
+                fetch(srv.url + "/cost", payload={"graph": graph},
+                      binary=False),
+            ]
+            with urllib.request.urlopen(srv.url + "/metrics") as r:
+                text = r.read().decode()
+        return [(etag, json.dumps(body, sort_keys=True))
+                for _, etag, body in got], text
+
+    on, text = serve()
+    for name in obs.SPAN_NAMES:  # every span's series, run or not
+        assert f'ndv_span_self_seconds_total{{span="{name}"}}' in text
+    for name in ("service.request", "service.compute", "planner.pick",
+                 "planner.compute_cost", "catalog.pack",
+                 "engine.device_wait"):
+        assert re.search(
+            rf'ndv_span_seconds_count\{{span="{re.escape(name)}"\}} [1-9]',
+            text,
+        ), name
+    assert re.search(r'ndv_pack_cells_total\{cell="padded"\} [1-9]', text)
+    obs.set_enabled(False)
+    try:
+        off, _ = serve()
+    finally:
+        obs.set_enabled(True)
+    assert off == on
+
+
+def test_span_names_are_the_documented_fixed_set():
+    import pathlib
+
+    from repro.obs.trace import SPAN_NAMES
+
+    src = pathlib.Path(obs.__file__).resolve().parent.parent
+    opened = set()
+    for path in src.rglob("*.py"):
+        if path.parent.name != "obs":
+            opened.update(re.findall(
+                r'(?:(?<!root_)span\(|timed_acquire\([^,]+,)\s*"([a-z0-9_.]+)"',
+                path.read_text()))
+    assert opened == set(SPAN_NAMES)
+    doc = (src.parent.parent / "docs" / "METRICS.md").read_text()
+    assert [n for n in SPAN_NAMES if f"`{n}`" not in doc] == []
+
+
+def test_span_series_exact_under_thread_stress():
+    import sys
+
+    before = _span_stats("t.stress")
+    n_threads, per_thread = 16, 1500
+    switch = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        def work():
+            for _ in range(per_thread):
+                with obs.span("t.stress"):
+                    pass
+
+        threads = [threading.Thread(target=work) for _ in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(switch)
+    n, secs, own = (a - b for a, b in zip(_span_stats("t.stress"), before))
+    assert n == n_threads * per_thread  # no queued span lost in a fold
+    assert own == pytest.approx(secs)
+
+
+def test_timed_span_keeps_ring_retention_unchanged():
+    from repro.service.service import SingleFlight
+
+    col = obs.collector()
+    before = _span_stats("t.timed")
+    with obs.root_span("t.warm"):
+        with obs.timed_span("t.timed") as sp:
+            assert sp.trace_id is None
+    assert _span_stats("t.timed")[0] - before[0] == 1  # timed all the same
+    assert _span_stats("t.warm") == (0, 0.0, 0.0)  # roots feed no series
+    flight = SingleFlight()
+    call, leader = flight.claim(("k",))
+    flight.finish(("k",), call, result=7)
+    with obs.root_span("t.follower"):
+        assert SingleFlight.wait(call) == 7
+    assert col.traces() == []  # both roots stayed childless
+    with obs.root_span("t.cold") as root:
+        with obs.timed_span("t.timed"):
+            assert obs.current_span() is root
+            assert obs.current_traceparent() == root.traceparent
+            with obs.span("t.inner") as inner:
+                assert inner.parent_id == root.span_id
+    (trace,) = col.traces()
+    assert [s.name for s in trace] == ["t.inner", "t.cold"]
+
+
+def test_warm_revalidation_trace_not_retained(dataset):
+    def revalidated():
+        return obs.registry().counter("ndv_http_requests_total").value(
+            tier="service", route="estimate", status="304")
+
+    with StatsServer(StatsService(dataset)) as srv:
+        url = srv.url + "/estimate?mode=improved"
+        status, etag, _ = fetch_json(url)
+        assert status == 200
+        tree = _poll_trace(srv.url + "/debug/traces?limit=5",
+                           "service.estimate")
+        # the body build hangs off the HTTP root; service.request is absent
+        assert [c["name"] for c in tree["children"]] == ["service.compute"]
+        obs.collector().clear()
+        before, requests = revalidated(), _span_stats("service.request")[0]
+        assert fetch_json(url, etag=etag)[0] == 304
+        deadline = time.monotonic() + 5
+        while revalidated() == before and time.monotonic() < deadline:
+            time.sleep(0.01)  # counted once the root span has exited
+        assert revalidated() == before + 1
+        assert _span_stats("service.request")[0] == requests + 1
+        assert obs.collector().traces() == []

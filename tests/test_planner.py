@@ -286,3 +286,27 @@ def test_batched_scorer_matches_reference_bit_for_bit(seed):
         assert cards[p].tobytes() == np.asarray(
             ref_cards, dtype=np.float32
         ).tobytes()
+
+
+def test_cold_cost_records_one_span_each_per_dispatch():
+    from repro.obs import registry
+
+    def counts():
+        hist = dict(registry().histogram("ndv_span_seconds").snapshot())
+        spans = {
+            name: hist[(("span", name),)].count
+            for name in ("planner.compute_cost", "planner.enumerate",
+                         "planner.score", "planner.fold", "planner.pick")
+        }
+        return spans, registry().counter("planner_dispatches_total").value()
+
+    g = _graph(5, [(0, 1), (1, 2), (2, 3), (3, 4)])
+    stats = _stats(g, {t.name: 1000 for t in g.tables},
+                   {t.name: 50 for t in g.tables})
+    spans0, dispatches0 = counts()
+    body = compute_cost(g, stats, mode="paper", max_plans=64)
+    assert body["enumeration"] == "sampled"
+    spans1, dispatches1 = counts()
+    assert dispatches1 - dispatches0 == 1
+    assert {k: spans1[k] - spans0[k] for k in spans1} == dict.fromkeys(
+        spans1, 1)

@@ -81,6 +81,33 @@ def test_estimate_bit_identical_to_catalog(served, dataset):
         assert got == ref  # dataclass equality: every field, bit-exact
 
 
+def test_tablestats_lock_wait_is_recorded(served):
+    from repro.obs import registry
+
+    def lock_wait_s():
+        return registry().counter("ndv_span_self_seconds_total").value(
+            span="service.lock_wait")
+
+    url = served.url + "/tablestats?mode=paper"
+    assert fetch_json(url)[0] == 200  # warm: the next call only waits
+    held = threading.Event()
+
+    def hold():
+        # Long enough that the request below, sent once the lock is held,
+        # still waits at least 50 ms for it.
+        with served.service.lock:
+            held.set()
+            time.sleep(0.25)
+
+    before = lock_wait_s()
+    holder = threading.Thread(target=hold)
+    holder.start()
+    held.wait(5)
+    assert fetch_json(url)[0] == 200
+    holder.join()
+    assert lock_wait_s() - before >= 0.05
+
+
 def test_revalidation_304_zero_packs_zero_engine_runs(served):
     url = served.url + "/estimate"
     svc = served.service
