@@ -14,13 +14,18 @@ join graph — served fleet-wide as `POST /cost`.
          ▼                                       ▼   per referenced dataset
     {name -> TableStats(rows, {col -> ColumnStats(ndv, conf, route)})}
          │ api.compute_cost
-         ├─ enumeration.enumerate_plans   all n! left-deep orders, or a
+         ├─ enumeration.plan_space        all n! left-deep orders, or a
          │    (planner.enumerate span)    fixed-seed sample — ONE (P, N)
-         │                                int32 array, deterministic
+         │                                int32 array, deterministic, and
+         │                                its lexicographic rank; built
+         │                                once per (n, max_plans), then a
+         │                                byte-bounded LRU cache lookup
          ├─ cost.score_plans              pack (rows, multipliers) lanes,
          │    (planner.score span)        pow2-pad P, fold C_out with one
          │                                jitted lax.scan — 1 dispatch
          │                                for thousands of plans
+         ├─ cost.best_plan_index          argmin over costs, ties to the
+         │    (planner.pick span)         lowest cached rank
          └─ best order + per-join cardinalities + total cost
               (?explain=1 adds per-column NDV/route/confidence provenance)
 

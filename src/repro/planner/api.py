@@ -26,7 +26,11 @@ from repro.planner.cost import (
     reference_cost,
     score_plans,
 )
-from repro.planner.enumeration import enumerate_plans, plan_space_size
+from repro.planner.enumeration import (
+    enumerate_plans,
+    plan_space,
+    plan_space_size,
+)
 from repro.planner.graph import JoinGraph
 
 __all__ = ["ColumnStats", "TableStats", "compute_cost", "provenance_block"]
@@ -120,11 +124,12 @@ def _cost_body(
         })
 
     with span("planner.enumerate", tables=n, max_plans=max_plans):
-        plans = enumerate_plans(n, max_plans)
+        space = plan_space(n, max_plans)
+    plans = space.plans
     with span("planner.score", plans=int(plans.shape[0]), tables=n):
         costs, step_cards = score_plans(plans, base_rows, factors)
     with span("planner.pick", plans=int(plans.shape[0])):
-        best = best_plan_index(plans, costs)
+        best = best_plan_index(plans, costs, space.rank)
     best_plan = [int(x) for x in plans[best]]
     best_order = [names[i] for i in best_plan]
 

@@ -34,7 +34,7 @@ reads of its results in a `planner.fold` span.
 from __future__ import annotations
 
 import functools
-from typing import Dict, List, Sequence, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.obs import registry, span
+from repro.planner.enumeration import plan_rank
 
 __all__ = [
     "EdgeFactor",
@@ -163,19 +164,24 @@ def score_plans(
     return costs, step_cards
 
 
-def best_plan_index(plans: np.ndarray, costs: np.ndarray) -> int:
+def best_plan_index(
+    plans: np.ndarray, costs: np.ndarray, rank: Optional[np.ndarray] = None
+) -> int:
     """Cheapest plan; ties broken by lexicographically smallest order.
 
     NaN costs (a zero-row table joined under sampled overflow, say) lose
-    to any finite cost; an all-NaN field degrades to the lexicographic
-    minimum — still deterministic across replicas.
+    to any other cost, infinities included; `-0.0` ties `0.0`. An all-NaN
+    field degrades to the lexicographic minimum — still deterministic
+    across replicas. `rank` is `plans`' `plan_rank` (the plan space
+    caches it); without it the pick computes it.
     """
-    p = plans.shape[0]
-    keys = [(float(costs[i]), tuple(int(x) for x in plans[i]))
-            for i in range(p)]
-    finite = [k for k in keys if k[0] == k[0]]
-    target = min(finite) if finite else min(keys, key=lambda k: k[1])
-    return keys.index(target)
+    if rank is None:
+        rank = plan_rank(plans)
+    costs = np.asarray(costs)
+    ordered = ~np.isnan(costs)
+    lanes = costs == costs[ordered].min() if ordered.any() else ~ordered
+    idx = np.flatnonzero(lanes)
+    return int(idx[np.argmin(rank[idx])])
 
 
 def reference_cost(

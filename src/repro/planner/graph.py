@@ -17,8 +17,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List, Optional, Tuple
 
-#: Enumeration cap when the request does not set one. 8! = 40320 exceeds
-#: it, so graphs of 8+ tables score a deterministic sample (`enumerate`).
+#: Enumeration cap when the request does not set one. 7! = 5040 exceeds
+#: it, so graphs of 7+ tables score a deterministic sample (`enumerate`).
 DEFAULT_MAX_PLANS = 4096
 
 #: Hard ceiling on the enumeration width a request may ask for — the

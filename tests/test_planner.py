@@ -13,6 +13,9 @@ The load-bearing claims pinned here:
   * the cost model degrades conservatively: NDV <= 0 clamps to 1 (edge
     becomes a pass-through), a join step with no connecting edge is a
     cross product, ties break on the lexicographically smallest plan
+  * each plan space is built once and shared read-only, bit-identical to
+    the loop it replaced, and the vectorized pick chooses exactly what
+    the tuple-keyed `min` did, so cached bodies are byte-identical
 """
 import math
 
@@ -310,3 +313,252 @@ def test_cold_cost_records_one_span_each_per_dispatch():
     assert dispatches1 - dispatches0 == 1
     assert {k: spans1[k] - spans0[k] for k in spans1} == dict.fromkeys(
         spans1, 1)
+
+
+# -- plan-space cache and vectorized pick -------------------------------------
+#
+# The oracles below are the planner's loops as they were before the plan
+# space was cached and the pick vectorized: the cached arrays and the
+# argmin must reproduce them exactly, or served bodies and ETags move.
+
+
+def _oracle_enumerate_plans(n_tables, max_plans):
+    import itertools
+
+    total = math.factorial(n_tables)
+    if total <= max_plans:
+        plans = np.fromiter(
+            itertools.chain.from_iterable(
+                itertools.permutations(range(n_tables))
+            ),
+            dtype=np.int32,
+            count=total * n_tables,
+        )
+        return plans.reshape(total, n_tables)
+
+    rng = np.random.default_rng(0)
+    seen = set()
+    out = []
+    identity = tuple(range(n_tables))
+    seen.add(identity)
+    out.append(identity)
+    while len(out) < max_plans:
+        perm = tuple(int(x) for x in rng.permutation(n_tables))
+        if perm not in seen:
+            seen.add(perm)
+            out.append(perm)
+    return np.array(out, dtype=np.int32)
+
+
+def _oracle_best_plan_index(plans, costs):
+    p = plans.shape[0]
+    keys = [(float(costs[i]), tuple(int(x) for x in plans[i]))
+            for i in range(p)]
+    finite = [k for k in keys if k[0] == k[0]]
+    target = min(finite) if finite else min(keys, key=lambda k: k[1])
+    return keys.index(target)
+
+
+def _space_cache_counts():
+    from repro.obs import registry
+
+    c = registry().counter("planner_space_cache_total")
+    return c.value(result="hit"), c.value(result="miss")
+
+
+@pytest.mark.parametrize("max_plans", [1, 24, 1000, 4096])
+@pytest.mark.parametrize("n_tables", range(1, 10))
+def test_cached_enumeration_matches_oracle(n_tables, max_plans):
+    plans = enumerate_plans(n_tables, max_plans)
+    want = _oracle_enumerate_plans(n_tables, max_plans)
+    assert plans.dtype == want.dtype == np.int32
+    assert np.array_equal(plans, want)
+
+
+@pytest.mark.parametrize("n_tables,max_plans", [(4, 24), (7, 1000), (9, 4096)])
+def test_plan_space_is_shared_read_only_and_ranked(n_tables, max_plans):
+    from repro.planner.enumeration import plan_space
+
+    space = plan_space(n_tables, max_plans)
+    assert plan_space(n_tables, max_plans) is space
+    assert enumerate_plans(n_tables, max_plans) is space.plans
+    for arr in (space.plans, space.rank):
+        with pytest.raises(ValueError):
+            arr[0] = 0
+    assert space.rank.dtype == np.int64
+    ranked = [tuple(int(x) for x in p) for p in space.plans[
+        np.argsort(space.rank)]]
+    assert ranked == sorted(tuple(int(x) for x in p) for p in space.plans)
+
+
+def _pick_case(case, rng, n):
+    costs = rng.random(n).astype(np.float32) * 1e6
+    if case == "ties":
+        lanes = rng.choice(n, size=max(2, n // 4), replace=False)
+        costs[lanes] = costs.min()
+    elif case == "signed_zero":
+        lanes = rng.choice(n, size=4, replace=False)
+        costs[lanes[:2]] = np.float32(-0.0)
+        costs[lanes[2:]] = np.float32(0.0)
+    elif case == "nan":
+        costs[rng.choice(n, size=n // 3, replace=False)] = np.nan
+    elif case == "inf":
+        costs[rng.choice(n, size=n // 5, replace=False)] = np.inf
+        costs[rng.choice(n, size=3, replace=False)] = -np.inf
+    elif case == "plus_inf_only":
+        costs[:] = np.inf
+        costs[rng.choice(n, size=n // 3, replace=False)] = np.nan
+    elif case == "all_nan":
+        costs[:] = np.nan
+    return costs
+
+
+@pytest.mark.parametrize("seed", range(3))
+@pytest.mark.parametrize("case", ["random", "ties", "signed_zero", "nan",
+                                  "inf", "plus_inf_only", "all_nan"])
+@pytest.mark.parametrize("n_tables,max_plans", [(4, 24), (7, 1000)])
+def test_vectorized_pick_matches_oracle(n_tables, max_plans, case, seed):
+    from repro.planner.cost import best_plan_index
+    from repro.planner.enumeration import plan_space
+
+    space = plan_space(n_tables, max_plans)
+    rng = np.random.default_rng(seed)
+    costs = _pick_case(case, rng, space.plans.shape[0])
+    want = _oracle_best_plan_index(space.plans, costs)
+    assert best_plan_index(space.plans, costs, space.rank) == want
+    assert best_plan_index(space.plans, costs) == want
+
+
+@pytest.mark.parametrize("cost", [0.0, -0.0, np.inf, -np.inf, np.nan])
+def test_vectorized_pick_single_plan(cost):
+    from repro.planner.cost import best_plan_index
+
+    plans = np.array([[2, 0, 1]], dtype=np.int32)
+    costs = np.array([cost], dtype=np.float32)
+    assert best_plan_index(plans, costs) == _oracle_best_plan_index(
+        plans, costs) == 0
+
+
+def test_vectorized_pick_repeated_order_takes_first():
+    from repro.planner.cost import best_plan_index
+
+    plans = np.array([[1, 0], [0, 1], [1, 0], [0, 1]], dtype=np.int32)
+    for costs in ([5.0, 3.0, 3.0, 3.0], [np.nan] * 4, [2.0, 9.0, 2.0, 9.0]):
+        costs = np.array(costs, dtype=np.float32)
+        assert best_plan_index(plans, costs) == _oracle_best_plan_index(
+            plans, costs)
+
+
+def test_space_cache_counts_miss_then_hit_per_cold_cost():
+    # A size no other test asks for, so the first /cost builds it.
+    g = _graph(5, [(0, 1), (1, 2), (2, 3), (3, 4)])
+    stats = _stats(g, {t.name: 1000 for t in g.tables},
+                   {t.name: 50 for t in g.tables})
+    hit0, miss0 = _space_cache_counts()
+    first = compute_cost(g, stats, mode="paper", max_plans=97)
+    hit1, miss1 = _space_cache_counts()
+    second = compute_cost(g, stats, mode="paper", max_plans=97)
+    hit2, miss2 = _space_cache_counts()
+    assert (hit1 - hit0, miss1 - miss0) == (0, 1)
+    assert (hit2 - hit1, miss2 - miss1) == (1, 0)
+    assert first == second
+
+
+def test_space_cache_over_budget_entry_is_a_miss_and_not_kept():
+    from repro.planner.enumeration import PlanSpaceCache
+
+    cache = PlanSpaceCache(max_bytes=1000)
+    small = cache.get(3, 6)  # 6 x (3 x 4 + 8) = 120 bytes, kept
+    hit0, miss0 = _space_cache_counts()
+    a = cache.get(6, 720)  # 720 x (6 x 4 + 8) bytes, far over 1000
+    b = cache.get(6, 720)
+    hit1, miss1 = _space_cache_counts()
+    assert (hit1 - hit0, miss1 - miss0) == (0, 2)
+    # Not kept, and it pushed out nothing that fits.
+    assert (6, 720) not in cache and len(cache) == 1
+    assert cache.get(3, 6) is small and cache.nbytes == small.nbytes
+    assert a is not b
+    assert np.array_equal(a.plans, _oracle_enumerate_plans(6, 720))
+
+
+def test_space_cache_evicts_least_recently_used_by_bytes():
+    from repro.planner.enumeration import PlanSpaceCache
+
+    one = PlanSpaceCache(max_bytes=1 << 20).get(4, 24).nbytes  # 24 x 24 B
+    cache = PlanSpaceCache(max_bytes=2 * one)
+    first = cache.get(4, 24)
+    cache.get(4, 23)
+    assert cache.get(4, 24) is first  # now the most recent
+    cache.get(4, 22)  # (4, 23) is least recent: it goes
+    assert (4, 24) in cache and (4, 22) in cache and (4, 23) not in cache
+    assert cache.nbytes <= cache.max_bytes
+    assert cache.nbytes == sum(
+        cache.get(*k).nbytes for k in [(4, 24), (4, 22)])
+
+
+def test_space_cache_threads_keep_byte_count_exact():
+    import os
+    import sys
+    import threading
+
+    from repro.planner.enumeration import PlanSpaceCache
+
+    sizes = [(n, m) for n in (3, 4, 5) for m in (5, 6, 24, 120)]
+    oracle = {k: _oracle_enumerate_plans(*k) for k in sizes}
+    cache = PlanSpaceCache(max_bytes=6000)
+    errors = []
+
+    def work(seed):
+        rng = np.random.default_rng(seed)
+        try:
+            for _ in range(150):
+                k = sizes[int(rng.integers(len(sizes)))]
+                if not np.array_equal(cache.get(*k).plans, oracle[k]):
+                    errors.append(k)
+        except Exception as exc:  # reported below, with the thread's seed
+            errors.append((seed, exc))
+
+    threads = [threading.Thread(target=work, args=(s,))
+               for s in range((os.cpu_count() or 4) + 4)]
+    switch = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(switch)
+    assert not any(t.is_alive() for t in threads)
+    assert not errors
+    kept = [cache.get(*k).nbytes for k in sizes if k in cache]
+    assert cache.nbytes == sum(kept) <= cache.max_bytes
+
+
+@pytest.mark.parametrize("n_tables", [13, 5])
+def test_cost_body_identical_to_oracle_path(monkeypatch, n_tables):
+    import json
+    import types
+
+    from repro.planner import api
+
+    rng = np.random.default_rng(n_tables)
+    g = _random_connected_graph(rng, n_tables)
+    rows = {t.name: float(rng.integers(10, 10**9)) for t in g.tables}
+    ndv = {t.name: float(rng.integers(1, 10**6)) for t in g.tables}
+    stats = _stats(g, rows, ndv)
+
+    body = compute_cost(g, stats, mode="improved",
+                        max_plans=DEFAULT_MAX_PLANS)
+    with monkeypatch.context() as m:
+        m.setattr(api, "plan_space", lambda n, mp: types.SimpleNamespace(
+            plans=_oracle_enumerate_plans(n, mp), rank=None))
+        m.setattr(api, "best_plan_index",
+                  lambda plans, costs, rank: _oracle_best_plan_index(
+                      plans, costs))
+        want = compute_cost(g, stats, mode="improved",
+                            max_plans=DEFAULT_MAX_PLANS)
+    assert body["enumeration"] == ("sampled" if n_tables > 6
+                                   else "exhaustive")
+    assert json.dumps(body, sort_keys=True) == json.dumps(want,
+                                                          sort_keys=True)
