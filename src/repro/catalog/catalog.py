@@ -443,15 +443,29 @@ class StatsCatalog:
             self._cache_put(self._batch_cache, key, batch)
         else:
             self._batch_cache.move_to_end(key)
-        # No target device: placement stays uncommitted (default device), so
-        # the sharded/composed strategies remain free to lay the batch out
-        # across their mesh without fighting a pinned placement.
+        # On the engine's own device when it has one (a replica placed on
+        # a chip keeps its packs there); otherwise uncommitted on the
+        # default device, so the sharded/composed strategies remain free to
+        # lay the batch out across their mesh.
         with _obs_span("engine.h2d", batch=int(batch.batch)):
-            resident = jax.device_put(batch)
+            resident = jax.device_put(batch, self.engine.committed_device)
             jax.block_until_ready(resident)
         self.stats.device_puts += 1
         self._cache_put(self._resident_cache, key, resident)
         return resident
+
+    def warm(self, *, mode: str = "paper") -> None:
+        """Warm the engine's program for the current state's (B, R) bucket
+        on the engine's devices (`EstimationEngine.warm`): no pack is
+        built and no cache line filled."""
+        self._ensure_scanned()
+        merged = self._merged or {}
+        if not merged:
+            return
+        self.engine.warm(
+            len(merged), max(m.num_row_groups for m in merged.values()),
+            mode=mode,
+        )
 
     @property
     def num_resident_batches(self) -> int:

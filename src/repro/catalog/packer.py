@@ -43,6 +43,29 @@ for _t in PhysicalType:
     _INT_LIKE[int(_t)] = _t.is_integer_like
 _BYTE_ARRAY = int(PhysicalType.BYTE_ARRAY)
 
+# Every field `_pack` fills: its dtype, and whether it is a (B, R) plane
+# (else one value per lane). `padding_batch` builds from it; a test holds
+# it to `_pack`'s output.
+_FIELDS = {
+    "chunk_S": (np.float32, True),
+    "chunk_rows": (np.float32, True),
+    "chunk_nulls": (np.float32, True),
+    "chunk_dict_encoded": (bool, True),
+    "N": (np.float32, False),
+    "nulls": (np.float32, False),
+    "n_groups": (np.int32, False),
+    "mins": (np.float32, True),
+    "maxs": (np.float32, True),
+    "valid": (bool, True),
+    "m_min": (np.float32, False),
+    "m_max": (np.float32, False),
+    "mean_len": (np.float32, False),
+    "len_sample": (np.int32, False),
+    "fixed_width": (bool, False),
+    "int_like": (bool, False),
+    "single_byte": (bool, False),
+}
+
 
 def bucket_size(n: int, floor: int = 1) -> int:
     """Round n up to the next power of two, at least `floor`."""
@@ -141,6 +164,17 @@ class BatchPacker:
             else max(int(max_groups), 1)
         )
         return b, r
+
+    def padding_batch(self, num_columns: int, max_groups: int) -> ColumnBatch:
+        """An all-padding batch in the bucket `pack` gives `num_columns`
+        columns of up to `max_groups` row groups: the same shapes and
+        dtypes as the real pack, and no real lane. Dispatching it compiles
+        the bucket's program without building a pack (warm-up)."""
+        B, R = self.shape_for(num_columns, max_groups)
+        return ColumnBatch(**{
+            name: jnp.asarray(np.zeros((B, R) if plane else (B,), dtype))
+            for name, (dtype, plane) in _FIELDS.items()
+        })
 
     def pack(self, cols: Sequence[ColumnMetadata]) -> ColumnBatch:
         """Pack per-column metadata into a padded struct-of-arrays batch."""

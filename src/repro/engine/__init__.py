@@ -44,6 +44,13 @@ strategies are numerics-neutral, they never enter `cache_key`/`cache_token`,
 so estimate caches, on-disk spills, and client ETag caches all survive
 strategy changes unchanged.
 
+An engine runs on its own devices (`EstimationEngine(devices=...)`, every
+local device by default). The fleet gives each replica a share of the
+host's chips: one chip each when there are at least as many replicas as
+chips, and a one-device engine resolves "auto" to local or chunked with
+its inputs committed to that chip. The device set, like the strategy,
+stays out of `cache_key`/`cache_token`.
+
 The config also carries the `kernels/ops` backend knob ("auto" / "pallas" /
 "ref"), which used to be unreachable from the public API: the engine threads
 it into `estimate_batch`, which routes the Newton inversions and the

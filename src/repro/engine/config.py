@@ -30,9 +30,9 @@ class EngineConfig:
         across the mesh AND chunk-stream each shard's slice under a
         per-shard budget — the strategy for meshes of small devices serving
         catalogs wider than any one device's memory), or "auto" — composed
-        when more than one device is visible and the batch exceeds the
-        mesh-wide budget (`num_shards * per-shard max_batch`), sharded when
-        more than one device is visible, otherwise chunked only when the
+        when the engine has more than one device and the batch exceeds
+        the mesh-wide budget (`num_shards * per-shard max_batch`), sharded
+        when it has more than one device, otherwise chunked only when the
         batch exceeds `max_batch`, otherwise local.
       backend: the `repro.kernels.ops` knob, threaded into `estimate_batch`.
         "auto" picks the fastest correct path per platform (compiled Pallas
@@ -40,7 +40,7 @@ class EngineConfig:
         is a correctness tool, not a serving path); "pallas" forces the
         kernels (interpreted off-TPU); "ref" forces the jnp reference.
       num_shards: device count for the sharded and composed strategies; 0
-        means all visible devices. Clamped to the visible device count at
+        means all the engine's devices. Clamped to that count at
         run time (the clamp is logged once per engine: under composed a
         silently wrong shard count would also silently change the
         per-shard chunk budget).

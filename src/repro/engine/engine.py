@@ -45,12 +45,14 @@ logger = logging.getLogger(__name__)
 
 _DISPATCHES = registry().counter(
     "ndv_engine_dispatches_total",
-    "Engine estimate() dispatches, by resolved strategy and mode",
+    "Engine estimate() dispatches, by resolved strategy, mode and the "
+    "engine's first device",
 )
 
 
-def detect_device_memory() -> Optional[int]:
-    """Bytes of memory on the first visible device; None on the host CPU.
+def detect_device_memory(device=None) -> Optional[int]:
+    """Bytes of memory on `device` (default: the first local device);
+    None on the host CPU.
 
     Accelerators (TPU/GPU) report it through the allocator's
     `memory_stats()`. The host CPU reports nothing, and the auto budget
@@ -58,7 +60,7 @@ def detect_device_memory() -> Optional[int]:
     empty report raises: shrinking the budget to the CPU default would
     hide a broken device behind a slower, still-working engine.
     """
-    dev = jax.local_devices()[0]
+    dev = device if device is not None else jax.local_devices()[0]
     if dev.platform == "cpu":
         return None
     stats = dev.memory_stats() or {}
@@ -155,10 +157,21 @@ def _pad_axis0(x: jnp.ndarray, target: int) -> jnp.ndarray:
 
 
 class EstimationEngine:
-    """Routes a packed `ColumnBatch` to one of three execution strategies."""
+    """Routes a packed `ColumnBatch` to one of three execution strategies.
 
-    def __init__(self, config: Optional[EngineConfig] = None):
+    `devices` are the engine's own: its shard count, memory report and
+    mesh read them, never the process-wide device list. The default is
+    every local device. The local and chunked strategies commit every
+    input to the first of them, so engines placed on different chips run
+    side by side.
+    """
+
+    def __init__(
+        self, config: Optional[EngineConfig] = None, devices=None
+    ):
         self.config = config or EngineConfig()
+        # Resolved on first use: constructing an engine touches no backend.
+        self._devices: Optional[tuple] = tuple(devices) if devices else None
         self._packer: Optional[BatchPacker] = None
         self._mem_checked = False
         self._mem_bytes: Optional[int] = None
@@ -168,20 +181,35 @@ class EstimationEngine:
     # -- identity ------------------------------------------------------------
 
     @property
+    def devices(self) -> tuple:
+        """The devices this engine runs on (default: every local device)."""
+        if self._devices is None:
+            self._devices = tuple(jax.local_devices())
+        return self._devices
+
+    @property
+    def committed_device(self):
+        """Where the catalog keeps this engine's packs: its only device, or
+        None (uncommitted) when it has several, so the sharded strategies
+        lay a batch out over their mesh."""
+        devices = self.devices
+        return devices[0] if len(devices) == 1 else None
+
+    @property
     def shard_count(self) -> int:
-        """Resolved shard count: config, clamped to visible devices.
+        """Resolved shard count: config, clamped to the engine's devices.
 
         The clamp is surfaced (one log line per engine, not per call): a
         `num_shards` larger than the mesh silently becoming "all devices"
         used to be invisible, and under the composed strategy a wrong shard
         count also silently changes the per-shard chunk budget.
         """
-        n_dev = jax.device_count()
+        n_dev = len(self.devices)
         want = self.config.num_shards or n_dev
         if want > n_dev and not self._clamp_logged:
             self._clamp_logged = True
             logger.warning(
-                "EngineConfig(num_shards=%d) exceeds the %d visible "
+                "EngineConfig(num_shards=%d) exceeds the engine's %d "
                 "device(s); clamping to %d (this also sets the composed "
                 "per-shard chunk budget)",
                 want, n_dev, n_dev,
@@ -256,7 +284,7 @@ class EstimationEngine:
         """The chunk budget this engine executes with.
 
         A fixed config value passes through; "auto" is derived per engine
-        from the first device's reported memory, detected once (fallback:
+        from its first device's reported memory, detected once (fallback:
         `DEFAULT_MAX_BATCH` where the backend reports none, e.g. host CPU).
         `shards > 1` is the composed strategy's PER-SHARD budget: the memory
         report is divided across the mesh before sizing (see
@@ -269,7 +297,7 @@ class EstimationEngine:
         if mb != "auto":
             return mb
         if not self._mem_checked:
-            self._mem_bytes = detect_device_memory()
+            self._mem_bytes = detect_device_memory(self.devices[0])
             self._mem_checked = True
         budget = self._auto_budgets.get(shards)
         if budget is None:
@@ -299,6 +327,16 @@ class EstimationEngine:
 
     # -- execution -----------------------------------------------------------
 
+    def warm(self, num_columns: int, max_groups: int, *, mode: str = "paper"
+             ) -> None:
+        """Compile (or load from JAX's cache) this engine's program for the
+        bucket of `num_columns` columns of up to `max_groups` row groups,
+        on its own devices, by one dispatch of an all-padding batch. Each
+        device keys its own executable, so a replica placed on a chip warms
+        its buckets there before a request needs them."""
+        batch = self.make_packer().padding_batch(num_columns, max_groups)
+        jax.block_until_ready(self.estimate(batch, mode=mode))
+
     def estimate(
         self,
         batch: ColumnBatch,
@@ -313,7 +351,17 @@ class EstimationEngine:
         mixes information across the B axis, so re-tiling B is exact.
         """
         strategy = self.resolve_strategy(batch.batch)
-        _DISPATCHES.inc(strategy=strategy, mode=mode)
+        if strategy in ("local", "chunked"):
+            # One device runs it: commit the inputs there. A no-op for a
+            # batch already resident (the catalog puts a one-device
+            # engine's packs on its device).
+            device = self.devices[0]
+            batch = jax.device_put(batch, device)
+            if schema_bound is not None:
+                schema_bound = jax.device_put(schema_bound, device)
+        _DISPATCHES.inc(
+            strategy=strategy, mode=mode, device=str(self.devices[0].id)
+        )
         # The enqueue only: JAX dispatches asynchronously, so the device's
         # time lands in the caller's `engine.device_wait` span.
         with _obs_span(
@@ -353,7 +401,7 @@ class EstimationEngine:
             # min(ndv, +inf) is the identity, bit-for-bit.
             schema_bound = jnp.full(batch.batch, np.inf, jnp.float32)
         fn = _sharded_fn(
-            tuple(jax.devices()[:n]), mode, self.config.backend,
+            self.devices[:n], mode, self.config.backend,
             self.config.fuse,
         )
         out = fn(batch, schema_bound)
@@ -400,7 +448,7 @@ class EstimationEngine:
         if schema_bound is None:
             schema_bound = jnp.full(batch.batch, np.inf, jnp.float32)
         fn = _sharded_fn(
-            tuple(jax.devices()[:n]), mode, self.config.backend,
+            self.devices[:n], mode, self.config.backend,
             self.config.fuse,
         )
         return self._stream_spans(batch, schema_bound, b, spans, fn)

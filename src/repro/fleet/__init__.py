@@ -40,6 +40,15 @@ replicas run `StatsService(shared_spill=True)`, so every computed entry is
 merged into the dataset's on-disk cache file and a freshly booted replica
 loads it before serving.
 
+Devices: `Fleet` divides the host's local devices among each dataset's
+replicas (`replica_devices`): with at least as many replicas as chips,
+replica i runs on chip i mod n, its packs resident there and its engine
+local to it; a single replica keeps every chip and shards across them.
+Replica i of every dataset shares a chip. A refresh is broadcast to the
+replicas one after another (`fleet.refresh_broadcast` span) and answered
+only when every replica has ingested it. The planner behind `/cost` runs
+in the router, on the default device.
+
 Batched RPC: the router's `POST /batch` accepts tuples spanning any mix of
 registered datasets in one frame (JSON or the binary wire encoding,
 negotiated per request). `Fleet.batch` groups tuples by dataset, each
@@ -82,5 +91,6 @@ from repro.fleet.router import (  # noqa: F401
     StatsRouter,
     default_replica_factory,
     make_router_handler,
+    replica_devices,
     serve_fleet,
 )

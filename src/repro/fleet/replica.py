@@ -158,6 +158,7 @@ class LocalReplica:
         root: str,
         *,
         engine_config: Optional[EngineConfig] = None,
+        devices=None,
         poll_interval: Optional[float] = None,
         max_workers: int = 8,
         audit: bool = False,
@@ -166,7 +167,9 @@ class LocalReplica:
         self.name = name
         self.service = StatsService(
             root,
-            engine=EstimationEngine(engine_config or EngineConfig()),
+            engine=EstimationEngine(
+                engine_config or EngineConfig(), devices=devices
+            ),
             poll_interval=poll_interval,
             max_workers=max_workers,
             shared_spill=True,
@@ -178,6 +181,9 @@ class LocalReplica:
 
     def start(self) -> "LocalReplica":
         self.service.start()
+        # The default mode's program for this dataset's bucket, compiled on
+        # this replica's own devices before the first request needs it.
+        self.service.catalog.warm()
         return self
 
     def stop(self) -> None:
@@ -556,23 +562,35 @@ class ReplicaSet:
         return list(responses), dispatches
 
     def refresh_all(self) -> List[Tuple[str, Optional[Response]]]:
-        """Broadcast a refresh to every replica (each replica ingests
-        independently; all must see a dataset change for their ETags to
-        agree). Transport failures eject, as in `call()`; a dataset-scoped
-        refresh error (e.g. a schema-mismatched new file — every replica
-        rejects it identically, last-good state keeps serving) is reported
-        as a failed entry without ejecting anyone."""
+        """Broadcast a refresh to every replica, one after another (each
+        replica ingests independently; all must see a dataset change for
+        their ETags to agree, and the refresh is acknowledged only once
+        they have). Transport failures eject, as in `call()`; a
+        dataset-scoped refresh error (e.g. a schema-mismatched new file —
+        every replica rejects it identically, last-good state keeps
+        serving) is reported as a failed entry without ejecting anyone."""
         out: List[Tuple[str, Optional[Response]]] = []
-        for replica in self.replicas:
-            try:
-                resp = replica.handle(StatsRequest("refresh"))
-            except Exception as e:
-                if isinstance(e, FAILOVER_ERRORS):
-                    self._mark(replica.name, False, f"{type(e).__name__}: {e}")
-                out.append((replica.name, None))
-                continue
-            self._mark(replica.name, True, None)
-            out.append((replica.name, resp))
+        refresh = StatsRequest("refresh")
+        with _obs_span(
+            "fleet.refresh_broadcast",
+            dataset=self.dataset_key, replicas=len(self.replicas),
+        ):
+            for replica in self.replicas:
+                try:
+                    with _obs_span(
+                        "replica.call",
+                        replica=replica.name, kind="refresh", attempt=1,
+                    ):
+                        resp = replica.handle(refresh)
+                except Exception as e:
+                    if isinstance(e, FAILOVER_ERRORS):
+                        self._mark(
+                            replica.name, False, f"{type(e).__name__}: {e}"
+                        )
+                    out.append((replica.name, None))
+                    continue
+                self._mark(replica.name, True, None)
+                out.append((replica.name, resp))
         return out
 
     # -- health --------------------------------------------------------------

@@ -65,6 +65,8 @@ from http.server import ThreadingHTTPServer
 from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 from urllib.parse import parse_qs, urlsplit
 
+import jax
+
 from repro.fleet.registry import DatasetRegistry, DatasetSpec
 from repro.fleet.replica import (
     LocalReplica,
@@ -106,10 +108,25 @@ _BATCH_WIDTH = obs_registry().histogram(
 )
 
 
+def replica_devices(index: int, replicas: int, devices: Sequence) -> tuple:
+    """Replica `index`'s share of the host's `devices`.
+
+    With at least as many replicas as devices, replica i gets device
+    i mod n; with fewer, it gets every device j with j mod replicas = i, so
+    a single replica keeps them all (and its engine shards as before).
+    Replica i of every dataset lands on the same share.
+    """
+    n = len(devices)
+    if replicas >= n:
+        return (devices[index % n],)
+    return tuple(d for j, d in enumerate(devices) if j % replicas == index)
+
+
 def default_replica_factory(
     spec: DatasetSpec, index: int, **replica_kwargs
 ) -> LocalReplica:
-    """Process-local replicas sharing the dataset's estimate-cache spill."""
+    """Process-local replicas sharing the dataset's estimate-cache spill,
+    each on the devices `Fleet` hands it (`devices=`)."""
     return LocalReplica(
         f"{spec.key}#{index}",
         spec.root,
@@ -154,11 +171,18 @@ class Fleet:
         # `+=` on the counters would lose increments under load.
         self._stats_mu = threading.Lock()
         factory = replica_factory or default_replica_factory
+        # Placement follows from what the host has: replica i of every
+        # dataset runs on the same share of the local devices.
+        local = jax.local_devices()
+        shares = [
+            replica_devices(i, replicas_per_dataset, local)
+            for i in range(replicas_per_dataset)
+        ]
         self.sets: Dict[str, ReplicaSet] = {
             spec.key: ReplicaSet(
                 spec.key,
                 [
-                    factory(spec, i, **replica_kwargs)
+                    factory(spec, i, devices=shares[i], **replica_kwargs)
                     for i in range(replicas_per_dataset)
                 ],
             )

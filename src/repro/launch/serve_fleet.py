@@ -52,7 +52,8 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--port", type=int, default=8090,
                     help="0 binds an ephemeral port")
     ap.add_argument("--replicas", type=int, default=2,
-                    help="replicas per dataset")
+                    help="replicas per dataset; they divide the local "
+                    "chips (one chip each when there are at least as many)")
     ap.add_argument("--refresh-interval", type=float, default=30.0,
                     help="per-replica ingestion poll seconds; 0 disables")
     ap.add_argument("--probe-interval", type=float, default=5.0,

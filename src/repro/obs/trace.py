@@ -67,7 +67,10 @@ TRACEPARENT_HEADER = "Traceparent"
 # values of the series' `span` label. HTTP roots, `<tier>.<route>`, are
 # bridged to the profiler but feed no span series.
 SPAN_NAMES = (
-    "replica.call",          # router root: one routed attempt
+    "replica.call",          # router root or fleet.refresh_broadcast:
+                             # one routed attempt
+    "fleet.refresh_broadcast",  # router root: one refresh, every replica
+                                # in turn
     "replica.sub_batch",     # router root: one /batch dispatch
     "service.request",       # a cacheable endpoint, whole (digest, 304);
                              # a timed_span: its children join the root

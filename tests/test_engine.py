@@ -537,7 +537,7 @@ def test_auto_budget_shrinks_per_shard(monkeypatch):
 
     calls = []
 
-    def fake_detect():
+    def fake_detect(device=None):
         calls.append(1)
         return 16 * 2**30
 
@@ -600,7 +600,7 @@ def test_resolve_max_batch_auto_detects_once(monkeypatch):
 
     calls = []
 
-    def fake_detect():
+    def fake_detect(device=None):
         calls.append(1)
         return 16 * 2**30
 
@@ -644,3 +644,38 @@ def test_auto_budget_chunked_parity_with_local():
         got = auto.estimate(batch, mode=mode)
         for f_ref, f_got in zip(ref, got):
             np.testing.assert_array_equal(np.asarray(f_ref), np.asarray(f_got))
+
+
+# -- warm-up ---------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("width,groups", [(1, 1), (5, 9), (20, 7)])
+def test_padding_batch_matches_the_pack_bucket(width, groups):
+    packer = BatchPacker()
+    cols = [_column(i, r=groups if i == 0 else 1) for i in range(width)]
+    real = packer.pack(cols)
+    pad = packer.padding_batch(width, groups)
+    assert [(x.shape, x.dtype, x.weak_type) for x in jax.tree.leaves(pad)] \
+        == [(x.shape, x.dtype, x.weak_type) for x in jax.tree.leaves(real)]
+    assert not np.asarray(pad.valid).any()
+
+
+def test_warm_compiles_the_bucket_a_real_estimate_then_reuses():
+    compiles = []
+
+    def listen(event, secs, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(secs)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    # A bucket no other test dispatches: 96 columns of up to 48 row groups.
+    cols = [_column(i, r=48 if i == 0 else 3) for i in range(70)]
+    ref = EstimationEngine(EngineConfig(strategy="local", backend="ref"))
+    want = ref.estimate_columns(cols, mode="improved")
+    eng = EstimationEngine(EngineConfig(strategy="local"))
+    compiles.clear()
+    eng.warm(len(cols), 48, mode="improved")
+    assert compiles
+    compiles.clear()
+    assert eng.estimate_columns(cols, mode="improved") == want
+    assert compiles == []
