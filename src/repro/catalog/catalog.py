@@ -3,12 +3,17 @@
 The catalog's contract (see the package docstring for the design):
 
   * `update()` scans the source, re-reading only footers whose fingerprint
-    changed, and maintains one merged `ColumnMetadata` per column. Pure
-    additions merge into the existing view (O(new files)); any rewrite or
-    removal triggers a full re-merge. The footer I/O and the commit are
-    split: `apply_footers()` is the atomic merge-and-swap seam, so the
-    async ingestor (`repro.service`) can scatter-gather footers on a thread
-    pool and commit through the same code path.
+    changed, and maintains one merged `ColumnMetadata` per column. Each
+    live file's per-column views are kept, keyed by file id and
+    fingerprint, so only new or rewritten footers are ever parsed. Pure
+    additions merge into the existing view (O(new files)); a rewrite or
+    removal re-merges every live file's kept views (no footer re-parsed).
+    Metric (`repro.obs` registry): `ndv_merge_views_total{result=
+    "reused"|"built"}`, one per (file, column) view a merge reads. The
+    footer I/O and the commit are split: `apply_footers()` is the atomic
+    merge-and-swap seam, so the async ingestor (`repro.service`) can
+    scatter-gather footers on a thread pool and commit through the same
+    code path.
   * `estimate()` packs the merged view through the bucketing `BatchPacker`
     and executes through an injected `EstimationEngine` (local / sharded /
     chunked / composed — see `repro.engine`). Packed batches are cached
@@ -50,6 +55,7 @@ import numpy as np
 
 from repro.catalog.merge import merge_column_metadata
 from repro.catalog.packer import BatchPacker
+from repro.obs import registry
 from repro.obs import span as _obs_span
 from repro.catalog.source import MetadataSource, PQLiteMetadataSource
 from repro.core.ndv.estimator import (
@@ -76,6 +82,17 @@ _CACHE_VERSION = 2
 # mtime reveals them.
 _SPILL_LOCKS: Dict[str, threading.Lock] = {}
 _SPILL_LOCKS_MU = threading.Lock()
+
+_MERGE_VIEWS = registry().counter(
+    "ndv_merge_views_total",
+    "Per-file column views merged: reused (kept) or built (footer parsed)",
+)
+_REUSED_VIEWS = _MERGE_VIEWS.labels(result="reused")
+_BUILT_VIEWS = _MERGE_VIEWS.labels(result="built")
+
+# A live file's views: the fingerprint they were built at, and one
+# `ColumnMetadata` per column name.
+_FileViews = Tuple[str, Dict[str, ColumnMetadata]]
 
 
 def _spill_lock(path: str) -> threading.Lock:
@@ -182,6 +199,8 @@ class StatsCatalog:
         self.stats = CatalogStats()
         self._entries: "OrderedDict[str, FileEntry]" = OrderedDict()
         self._merged: Optional[Dict[str, ColumnMetadata]] = None
+        # file id -> its views; exactly the files of `_entries`.
+        self._views: Dict[str, _FileViews] = {}
         self._column_names: List[str] = []
         self._batch_cache: "OrderedDict[frozenset, ColumnBatch]" = OrderedDict()
         self._resident_cache: "OrderedDict[frozenset, ColumnBatch]" = (
@@ -215,7 +234,7 @@ class StatsCatalog:
         previously ingested, never as added — the same semantics the async
         ingestion path (`repro.service.AsyncIngestor`) applies.
 
-        All catalog state (entries, merged view, cached fingerprint key) is
+        All catalog state (entries, views, merged view, fingerprint key) is
         committed only after merging succeeds, so a failed update — e.g. a
         schema-mismatched file — leaves the previous consistent view intact.
         """
@@ -250,6 +269,11 @@ class StatsCatalog:
         fingerprint matches the existing one (an ingestion race re-read an
         unchanged footer) is a no-op, not an update.
 
+        Views are built only for the fresh entries applied; every other
+        live file's kept views are reused, and a rewrite or removal merges
+        all of them in `live_ids` order — the same view a merge of every
+        footer from scratch gives.
+
         This is the single commit point for both the synchronous `update()`
         loop and the scatter-gathered async path: footer I/O can happen
         anywhere, concurrently, while the merge + state swap stays atomic —
@@ -283,17 +307,19 @@ class StatsCatalog:
         removed = len(set(self._entries) - set(new_entries))
         pure_addition = updated == 0 and removed == 0
         if not new_entries:
-            merged, names = {}, []
+            merged, names, views = {}, [], {}
         elif self._merged is not None and pure_addition and not applied:
             merged, names = self._merged, self._column_names
+            views = self._views
         elif self._merged and pure_addition:
-            merged, names = self._merge_into(applied)
+            merged, names, views = self._merge_into(applied)
         else:
-            merged, names = self._merge_all(list(new_entries.values()))
+            merged, names, views = self._merge_all(list(new_entries.values()))
         # commit point: merge succeeded, swap the whole view atomically
         self._scanned = True
         self._entries = new_entries
         self._merged, self._column_names = merged, names
+        self._views = views
         self._fp_key = None
         summary = UpdateSummary(added, updated, removed, len(new_entries))
         if summary.changed:
@@ -304,9 +330,11 @@ class StatsCatalog:
             self._resident_cache.clear()
         return summary
 
-    def _per_file(self, entry: FileEntry, names: Sequence[str]) -> List[ColumnMetadata]:
+    def _per_file(
+        self, entry: FileEntry, names: Sequence[str]
+    ) -> Dict[str, ColumnMetadata]:
         try:
-            return [self.source.column_metadata(entry.footer, n) for n in names]
+            return {n: self.source.column_metadata(entry.footer, n) for n in names}
         except KeyError as e:
             raise ValueError(
                 f"file {entry.file_id!r} is missing column {e.args[0]!r} "
@@ -328,13 +356,23 @@ class StatsCatalog:
         names = list(entries[0].footer.column_names)
         for e in entries[1:]:
             self._check_schema(names, e)
-        per_file = [self._per_file(e, names) for e in entries]
+        views: Dict[str, _FileViews] = {}
+        built = 0
+        for e in entries:
+            held = self._views.get(e.file_id)
+            if held is None or held[0] != e.fingerprint:
+                held = (e.fingerprint, self._per_file(e, names))
+                built += 1
+            views[e.file_id] = held
+        per_file = [views[e.file_id][1] for e in entries]
         merged = {
-            name: merge_column_metadata([pf[i] for pf in per_file])
-            for i, name in enumerate(names)
+            name: merge_column_metadata([pf[name] for pf in per_file])
+            for name in names
         }
         self.stats.merges += 1
-        return merged, names
+        _REUSED_VIEWS.inc((len(entries) - built) * len(names))
+        _BUILT_VIEWS.inc(built * len(names))
+        return merged, names, views
 
     def _merge_into(self, fresh: List[FileEntry]) -> tuple:
         names = self._column_names
@@ -342,12 +380,16 @@ class StatsCatalog:
             self._check_schema(names, e)
         per_file = [self._per_file(e, names) for e in fresh]
         merged = dict(self._merged)
-        for i, name in enumerate(names):
+        for name in names:
             merged[name] = merge_column_metadata(
-                [merged[name]] + [pf[i] for pf in per_file]
+                [merged[name]] + [pf[name] for pf in per_file]
             )
+        views = dict(self._views)
+        for e, pf in zip(fresh, per_file):
+            views[e.file_id] = (e.fingerprint, pf)
         self.stats.merges += 1
-        return merged, names
+        _BUILT_VIEWS.inc(len(fresh) * len(names))
+        return merged, names, views
 
     def _ensure_scanned(self) -> None:
         if not self._scanned:

@@ -218,6 +218,180 @@ def test_apply_footers_rejects_unknown_live_id():
         catalog.apply_footers([], live_ids=["a", "mystery"])
 
 
+# -- per-file views kept across commits ---------------------------------------
+
+
+def _view_shard(seed, rows=256):
+    rng = np.random.default_rng(seed)
+    words = np.array(["alpha", "beta", "gamma", "delta", "epsilon"])
+    return {
+        "tok": rng.integers(0, 32, rows).astype(np.int64),
+        "val": np.round(rng.uniform(0, 10, rows), 1),
+        # every value shares the 8-byte prefix "shared__": only the length
+        # and the repr tell the row-group extrema apart
+        "s": np.char.add("shared__", rng.choice(words, rows)),
+    }
+
+
+def _view_footer(seed):
+    return write_file_footer(_view_shard(seed), rg=64)
+
+
+def _merge_view_counts():
+    from repro.obs import registry
+
+    c = registry().counter("ndv_merge_views_total")
+    return c.value(result="reused"), c.value(result="built")
+
+
+def _assert_same_view(got, want):
+    for f in dataclasses.fields(ColumnMetadata):
+        a, b = getattr(got, f.name), getattr(want, f.name)
+        if isinstance(b, np.ndarray):
+            assert isinstance(a, np.ndarray) and a.dtype == b.dtype, f.name
+            assert np.array_equal(a, b), f.name
+        else:
+            assert type(a) is type(b) and a == b, f.name
+
+
+def _assert_views_are_the_live_files(catalog):
+    assert {fid: fp for fid, (fp, _) in catalog._views.items()} == (
+        catalog.entry_fingerprints()
+    )
+    for fp, views in catalog._views.values():
+        assert sorted(views) == sorted(catalog.column_names)
+
+
+def _assert_merges_like_every_footer(catalog):
+    """The merged view equals a merge of every live footer from scratch
+    (a catalog holding no views), field by field and in chunk order."""
+    live = list(catalog._entries.values())
+    want, names, _ = StatsCatalog(catalog.source)._merge_all(live)
+    got = catalog.merged_metadata()
+    assert list(got) == list(want) and catalog.column_names == names
+    for name in names:
+        _assert_same_view(got[name], want[name])
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_kept_views_merge_like_every_footer_from_scratch(seed):
+    from repro.catalog import FileEntry
+    from repro.service import AsyncIngestor
+
+    rng = np.random.default_rng(seed)
+    contents = iter(range(100 * seed + 1000, 10**6))
+    src = _VanishingSource(
+        {f"f{i:02d}": _view_footer(next(contents)) for i in range(6)})
+    catalog = StatsCatalog(src)
+    ingestor = AsyncIngestor(catalog, max_workers=2)
+    catalog.update()
+    _assert_views_are_the_live_files(catalog)
+    _assert_merges_like_every_footer(catalog)
+    next_id = 6
+    kinds = ["insert", "rewrite", "delete", "reread", "vanish", "mixed"]
+    seen = set()
+    try:
+        for step, kind in enumerate(rng.permutation(kinds * 4)):
+            live = [f for f in src.list_files() if f not in src.vanished]
+            if kind in ("delete", "vanish") and len(live) <= 2:
+                kind = "insert"
+            pick = str(rng.choice(live))
+            if kind in ("insert", "mixed"):
+                src.add(f"f{next_id:02d}", _view_footer(next(contents)))
+                next_id += 1
+            if kind in ("rewrite", "mixed"):
+                src.add(pick, _view_footer(next(contents)))
+            if kind == "delete":
+                src.remove(pick)
+            if kind == "mixed":
+                src.remove(str(rng.choice([f for f in live if f != pick])))
+            if kind == "vanish":
+                src.remove(pick)
+                src.vanished.add(pick)
+            if kind == "reread":
+                # an ingestion race re-read an unchanged footer: no change
+                fresh = [FileEntry(pick, src.fingerprint(pick),
+                                   src.read_footer(pick))]
+                summary = catalog.apply_footers(fresh, live_ids=live)
+                assert not summary.changed
+            elif step % 2:
+                assert ingestor.refresh().changed
+            else:
+                assert catalog.update().changed
+            seen.add(kind)
+            _assert_views_are_the_live_files(catalog)
+            _assert_merges_like_every_footer(catalog)
+    finally:
+        ingestor.stop()
+    assert seen == set(kinds)
+
+
+@pytest.mark.parametrize("how", ["rewrite", "insert"])
+@pytest.mark.parametrize("bad", ["columns", "type"])
+def test_schema_mismatch_keeps_entries_views_and_merged_view(how, bad):
+    src = InMemoryMetadataSource(
+        {f: _view_footer(i) for i, f in enumerate("abcd")})
+    catalog = StatsCatalog(src)
+    catalog.update()
+    src.remove("a")
+    catalog.update()  # a re-merge from kept views
+    entries, views, merged = catalog._entries, catalog._views, catalog._merged
+    held = {fid: (fp, dict(v)) for fid, (fp, v) in views.items()}
+    before = catalog.estimate()
+    if bad == "columns":
+        footer = write_file_footer({"other": np.arange(64)})
+    else:  # same names, but "tok" turns from INT64 to DOUBLE
+        footer = write_file_footer(
+            {**_view_shard(9), "tok": np.arange(256, dtype=np.float64)},
+            rg=64)
+    src.add("c" if how == "rewrite" else "e", footer)
+    with pytest.raises(ValueError):
+        catalog.update()
+    assert catalog._entries is entries and catalog._merged is merged
+    assert catalog._views is views
+    assert {fid: (fp, dict(v)) for fid, (fp, v) in views.items()} == held
+    assert all(v[n] is held[fid][1][n]
+               for fid, (_, v) in views.items() for n in v)
+    _assert_views_are_the_live_files(catalog)
+    assert catalog.estimate() == before
+
+
+def test_rewrite_builds_views_only_for_the_rewritten_file(monkeypatch):
+    from repro.service import AsyncIngestor
+
+    src = InMemoryMetadataSource(
+        {f"f{i}": _view_footer(i) for i in range(5)})
+    catalog = StatsCatalog(src)
+    catalog.update()
+    parsed = []
+    per_file = catalog._per_file
+
+    def counting(entry, names):
+        parsed.append(entry.file_id)
+        return per_file(entry, names)
+
+    monkeypatch.setattr(catalog, "_per_file", counting)
+    reused0, built0 = _merge_view_counts()
+    src.add("f2", _view_footer(22))
+    assert catalog.update().updated == 1
+    assert parsed == ["f2"]
+    # three columns: four files' views reused, one file's built
+    assert _merge_view_counts() == (reused0 + 12, built0 + 3)
+    src.remove("f0")  # a delete parses nothing
+    assert catalog.update().removed == 1
+    assert parsed == ["f2"]
+    assert _merge_view_counts() == (reused0 + 24, built0 + 3)
+    ingestor = AsyncIngestor(catalog, max_workers=2)
+    try:
+        src.add("f4", _view_footer(44))
+        assert ingestor.refresh().updated == 1
+    finally:
+        ingestor.stop()
+    assert parsed == ["f2", "f4"]
+    assert _merge_view_counts() == (reused0 + 33, built0 + 6)
+    _assert_merges_like_every_footer(catalog)
+
+
 # -- persistent-cache hygiene ------------------------------------------------
 
 
